@@ -69,6 +69,9 @@ def main() -> None:
     os.environ.setdefault("XLA_FLAGS",
                           "--xla_force_host_platform_device_count=4")
 
+    from repro.launch.compile_cache import enable_compile_cache
+    print(f"# compile cache: {enable_compile_cache()}", file=sys.stderr)
+
     tracer = None
     if args.trace or args.trace_out:
         # before any bench runs: engines bind the process tracer at

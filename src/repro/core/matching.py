@@ -69,22 +69,29 @@ class _PropIndex:
 
 
 def _expand(values: np.ndarray, sorted_keys: np.ndarray,
-            payload: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
+            payload: np.ndarray, limit: Optional[int] = None
+            ) -> Tuple[np.ndarray, np.ndarray, bool]:
     """For each v in values, find all payload entries whose key == v.
 
-    Returns (row_index, payload_value) of the expanded join.
+    Returns (row_index, payload_value, cut) of the expanded join.  With
+    ``limit`` only the first ``limit`` result rows are built (``cut``
+    says rows were dropped), so a hub join whose full expansion would
+    not fit in memory never materializes it.
     """
     lo = np.searchsorted(sorted_keys, values, side="left")
     hi = np.searchsorted(sorted_keys, values, side="right")
     counts = hi - lo
+    cut = limit is not None and int(counts.sum()) > limit
+    if cut:
+        counts = np.diff(np.minimum(np.cumsum(counts), limit), prepend=0)
     row_idx = np.repeat(np.arange(len(values)), counts)
     if len(row_idx) == 0:
-        return row_idx, np.zeros(0, payload.dtype)
+        return row_idx, np.zeros(0, payload.dtype), cut
     # positions within each run
     starts = np.repeat(lo, counts)
     offs = np.arange(len(starts)) - np.repeat(
         np.concatenate([[0], np.cumsum(counts)[:-1]]), counts)
-    return row_idx, payload[starts + offs]
+    return row_idx, payload[starts + offs], cut
 
 
 def match_pattern(graph: RDFGraph, pattern: QueryGraph,
@@ -134,31 +141,28 @@ def match_pattern(graph: RDFGraph, pattern: QueryGraph,
             cols = {v: c[keep] for v, c in cols.items()}
             nrows = int(keep.sum())
         elif s_bound:
+            # e.dst is a fresh variable (a constant or bound dst takes
+            # the pair filter above), so every expanded row is kept and
+            # the ``max_rows`` cut applies to answer rows only
             keys, payload = (idx.by_subject(e.prop) if e.prop >= 0 else table_by_s)
-            row_idx, new_vals = _expand(col_of(e.src), keys, payload)
+            row_idx, new_vals, cut = _expand(col_of(e.src), keys,
+                                             payload, max_rows)
+            truncated |= cut
             cols = {v: c[row_idx] for v, c in cols.items()}
-            if e.dst < 0:
-                cols[e.dst] = new_vals
-                nrows = len(new_vals)
-            else:  # dst constant: filter
-                keep = new_vals == e.dst
-                cols = {v: c[keep] for v, c in cols.items()}
-                nrows = int(keep.sum())
-        elif d_bound:
+            cols[e.dst] = new_vals
+            nrows = len(new_vals)
+        elif d_bound:  # e.src is a fresh variable, likewise
             if e.prop >= 0:
                 keys, payload = idx.by_object(e.prop)
             else:
                 tbl_o = np.argsort(graph.o, kind="stable")
                 keys, payload = graph.o[tbl_o], graph.s[tbl_o]
-            row_idx, new_vals = _expand(col_of(e.dst), keys, payload)
+            row_idx, new_vals, cut = _expand(col_of(e.dst), keys,
+                                             payload, max_rows)
+            truncated |= cut
             cols = {v: c[row_idx] for v, c in cols.items()}
-            if e.src < 0:
-                cols[e.src] = new_vals
-                nrows = len(new_vals)
-            else:
-                keep = new_vals == e.src
-                cols = {v: c[keep] for v, c in cols.items()}
-                nrows = int(keep.sum())
+            cols[e.src] = new_vals
+            nrows = len(new_vals)
         else:
             # first edge (or disconnected component): scan the whole table
             if e.prop >= 0:

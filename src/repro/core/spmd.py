@@ -64,10 +64,14 @@ device; ``SpmdEngine`` transparently re-executes with doubled capacity
 (geometric, compile cached per capacity tier) until the answer is exact
 or ``max_capacity`` is hit, which raises instead of truncating.
 
-The expansion probes (join multiplicities per binding row) run through
-the blocked Pallas kernels in ``repro.kernels`` on TPU, with the
-``kernels.ref`` jnp oracles as the CPU fallback
-(``REPRO_SPMD_PALLAS=1/0`` overrides the backend-based default).
+The expansion probes (join multiplicities per binding row) and the
+cycle-close pair probe run through the blocked Pallas kernels in
+``repro.kernels`` on TPU (``TPU_KERNELS``); the gathered-row dedup and
+the fused dedup->expand->filter join have Pallas kernels too, used only
+when forced.  The ``kernels.ref`` jnp oracles and the jnp composition
+are the CPU path (``REPRO_SPMD_PALLAS=1/0`` forces every kernel on or
+off).  Which path each join step traced is counted per compiled matcher
+(``JOIN_PATHS``, ``stats().extra["traced_*"]``).
 """
 from __future__ import annotations
 
@@ -79,7 +83,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..constants import INT32_SENTINEL
 from ..kernels import ref as kref
@@ -140,6 +144,12 @@ class SiteStore:
     padded (m, e_max) columns on every traced step.  Arrays are padded
     ``csr_pad`` rows past the last run so a window never clamps into a
     neighbouring property.
+
+    With ``sharding`` (the engine passes ``NamedSharding(mesh, P(axis,
+    None))``) every per-device array is placed row-sharded over the mesh
+    axis as it is built, so each device holds only its own sites' rows
+    and the matcher's ``shard_map`` consumes them without a reshard;
+    without it they land on the default device.
     """
     s: jax.Array
     p: jax.Array
@@ -160,7 +170,9 @@ class SiteStore:
 
     @staticmethod
     def build(graph: RDFGraph, site_edge_ids: Sequence[np.ndarray],
-              pad_multiple: int = 512) -> "SiteStore":
+              pad_multiple: int = 512,
+              sharding: Optional[jax.sharding.Sharding] = None
+              ) -> "SiteStore":
         m = len(site_edge_ids)
         e_max = max((len(e) for e in site_edge_ids), default=1)
         e_max = int(np.ceil(max(e_max, 1) / pad_multiple) * pad_multiple)
@@ -219,12 +231,14 @@ class SiteStore:
             obj_o[j, :n], obj_s[j, :n] = o[order_o], s[order_o]
             offs[j, 1:] = np.cumsum(
                 np.bincount(p, minlength=n_props)[:n_props])
-        return SiteStore(jnp.asarray(S), jnp.asarray(Pm), jnp.asarray(O),
+        def put(a: np.ndarray) -> jax.Array:
+            return (jnp.asarray(a) if sharding is None
+                    else jax.device_put(a, sharding))
+
+        return SiteStore(put(S), put(Pm), put(O),
                          m, e_max, dev_rows, dev_distinct, union,
-                         jnp.asarray(sub_s), jnp.asarray(sub_o),
-                         jnp.asarray(obj_o), jnp.asarray(obj_s),
-                         jnp.asarray(offs), pad, dev_owned,
-                         jnp.asarray(owned))
+                         put(sub_s), put(sub_o), put(obj_o), put(obj_s),
+                         put(offs), pad, dev_owned, put(owned))
 
     def prop_shard_complete(self, prop: int) -> bool:
         """Every device holds every resident edge of ``prop`` (so a join
@@ -430,41 +444,92 @@ def _edge_table_for_prop(s: jax.Array, p: jax.Array, o: jax.Array,
     return keys[order], o[order]
 
 
-def _use_pallas_probes() -> bool:
-    """Pallas probe kernels on TPU; jnp oracles elsewhere.  The env knob
-    ``REPRO_SPMD_PALLAS`` forces the choice (tests exercise the kernel
-    path in interpret mode on CPU through it)."""
+#: The join kernels the match loop calls: ``join_count``, ``pair_semijoin``,
+#: ``dedup_rows``, ``fused_join``.  All four compile for TPU v5e
+#: (``tests/test_tpu_compile.py``).
+MATCH_KERNELS = ("join_count", "pair_semijoin", "dedup_rows", "fused_join")
+
+#: The kernels on by default on TPU.  ``dedup_rows`` and ``fused_join`` are
+#: off there by decision: they are serial scalar programs whose operands
+#: all live in SMEM (1 MiB on a v5e core), far less than a gathered step
+#: of the served capacity tiers, and no chip run shows them beating the
+#: jnp composition at a size that fits.
+TPU_KERNELS = ("join_count", "pair_semijoin")
+
+
+def _use_pallas_probes(kernel: str) -> bool:
+    """Is the Pallas ``kernel`` (one of ``MATCH_KERNELS``) on?  On TPU the
+    ``TPU_KERNELS``; jnp oracles elsewhere.  The env knob
+    ``REPRO_SPMD_PALLAS`` forces all four on or off (tests exercise the
+    kernel path in interpret mode on CPU through it; forced on a chip,
+    the SMEM kernels compile only for tables that fit SMEM)."""
     env = os.environ.get("REPRO_SPMD_PALLAS")
     if env is not None:
         return env not in ("0", "false", "")
-    return jax.default_backend() == "tpu"
+    from ..kernels.ops import _on_tpu
+    return _on_tpu() and kernel in TPU_KERNELS
 
 
-def _probe_counts(probe: jax.Array, keys_sorted: jax.Array) -> jax.Array:
+#: Trace-time join-path counters of one compiled matcher, in the order
+#: ``stats().extra`` reports them (as ``traced_<name>``):
+#:
+#: * ``join_count_kernel`` / ``join_count_jnp`` -- expansion-size
+#:   probes traced through the blocked kernel / the searchsorted oracle;
+#: * ``pair_semijoin_kernel`` / ``pair_semijoin_jnp`` -- cycle-close
+#:   probes, likewise;
+#: * ``hash_dedup_kernel`` / ``lexsort_dedup`` -- gathered-row dedups
+#:   traced through the hash kernel / the jnp lexsort;
+#: * ``fused_join_kernel`` / ``jnp_join`` -- binding-gather expansion
+#:   steps traced through the fused kernel / the dedup + ``_expand_fixed``
+#:   composition.
+#:
+#: Both branches of a planner ``lax.cond`` are traced, so a dynamic step
+#: counts the paths of both.
+JOIN_PATHS = ("join_count_kernel", "join_count_jnp",
+              "pair_semijoin_kernel", "pair_semijoin_jnp",
+              "hash_dedup_kernel", "lexsort_dedup",
+              "fused_join_kernel", "jnp_join")
+
+
+def _note(paths: Optional[Dict[str, int]], name: str) -> None:
+    """Count one traced ``name`` path (see ``JOIN_PATHS``)."""
+    if paths is not None:
+        paths[name] = paths.get(name, 0) + 1
+
+
+def _probe_counts(probe: jax.Array, keys_sorted: jax.Array,
+                  paths: Optional[Dict[str, int]] = None) -> jax.Array:
     """Join multiplicity of each probe key in a sorted key column -- the
     expansion-size probe of the match loop.  Blocked Pallas ``join_count``
     kernel (jit-safe static block plan) on TPU, ``kernels.ref`` oracle on
     CPU.  Sentinel table rows (INT32_MAX) never equal a real vertex id."""
-    if _use_pallas_probes():
+    if _use_pallas_probes("join_count"):
         from ..kernels.ops import join_count
+        _note(paths, "join_count_kernel")
         return join_count(probe, keys_sorted, jit_safe=True)
+    _note(paths, "join_count_jnp")
     return kref.join_count_ref(probe, keys_sorted)
 
 
 def _probe_pair_member(q_s: jax.Array, q_o: jax.Array,
-                       t_s: jax.Array, t_o: jax.Array) -> jax.Array:
+                       t_s: jax.Array, t_o: jax.Array,
+                       paths: Optional[Dict[str, int]] = None
+                       ) -> jax.Array:
     """(q_s[i], q_o[i]) present among the table's (s, o) pairs?  The
     cycle-close probe: exact int32 pair membership (no 42-bit key
     composition, which would need the x64 mode jax disables by default).
     Blocked Pallas ``pair_semijoin`` on TPU, merge-rank oracle on CPU."""
-    if _use_pallas_probes():
+    if _use_pallas_probes("pair_semijoin"):
         from ..kernels.ops import pair_semijoin
+        _note(paths, "pair_semijoin_kernel")
         return pair_semijoin(q_s, q_o, t_s, t_o, jit_safe=True)
+    _note(paths, "pair_semijoin_jnp")
     return kref.pair_semijoin_ref(q_s, q_o, t_s, t_o)
 
 
 def _expand_fixed(bind: jax.Array, valid: jax.Array, col_vals: jax.Array,
-                  keys_sorted: jax.Array, payload: jax.Array, capacity: int
+                  keys_sorted: jax.Array, payload: jax.Array, capacity: int,
+                  paths: Optional[Dict[str, int]] = None
                   ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Join-expand a binding table against a sorted (keys -> payload)
     edge table with a fixed output capacity.
@@ -477,7 +542,7 @@ def _expand_fixed(bind: jax.Array, valid: jax.Array, col_vals: jax.Array,
     C, V = bind.shape
     probe = jnp.where(valid, col_vals, jnp.iinfo(jnp.int32).max)
     lo = jnp.searchsorted(keys_sorted, probe, side="left")
-    cnt = jnp.where(valid, _probe_counts(probe, keys_sorted), 0)
+    cnt = jnp.where(valid, _probe_counts(probe, keys_sorted, paths), 0)
     cnt = cnt.astype(jnp.int32)
     # int32 cumsum can wrap past 2^31 total expansion rows and defeat
     # the overflow check (x64 is off, so no int64).  sum(cnt) cannot
@@ -502,7 +567,8 @@ def _expand_fixed(bind: jax.Array, valid: jax.Array, col_vals: jax.Array,
     return new_bind, new_col, ok, over
 
 
-def _dedup_padded(bind: jax.Array, valid: jax.Array
+def _dedup_padded(bind: jax.Array, valid: jax.Array,
+                  paths: Optional[Dict[str, int]] = None
                   ) -> Tuple[jax.Array, jax.Array]:
     """Invalidate duplicate rows of a padded binding table (exact -- no
     lossy hashing; row order never matters downstream).  After an
@@ -510,25 +576,26 @@ def _dedup_padded(bind: jax.Array, valid: jax.Array
     (replicated fragments); deduping before expansion keeps capacity
     pressure at the number of *distinct* partial matches.
 
-    On the kernel path (``REPRO_SPMD_PALLAS`` / TPU default) this runs
-    the open-addressed hash-dedup Pallas kernel -- O(n) inserts with
-    full-row compare on collision, keep mask in place -- replacing the
-    O(n log n) column-wise ``jnp.lexsort``.  Off-TPU (or beyond the
-    kernel's static VMEM budget) the lexsort oracle below is the
-    implementation of record: rows come back sorted there, in place on
-    the kernel path; no caller observes the order."""
+    With the ``dedup_rows`` kernel on (``REPRO_SPMD_PALLAS=1``) this
+    runs the open-addressed hash-dedup Pallas kernel -- O(n) inserts
+    with full-row compare on collision, keep mask in place -- instead of
+    the O(n log n) column-wise lexicographic sort (``lexsort_passes``).
+    That sort is the default and the implementation of record: rows
+    come back sorted there, in place on the kernel path; no caller
+    observes the order."""
     C, V = bind.shape
     if V == 0:
         keep = jnp.zeros_like(valid).at[0].set(valid.any())
         return bind, keep
-    if _use_pallas_probes():
-        from ..kernels.ops import dedup_rows, dedup_rows_supported
-        if dedup_rows_supported(C, V):
-            keep = dedup_rows(bind, valid)
-            return jnp.where(keep[:, None], bind, -1), keep
+    from ..kernels.ops import dedup_rows, lexsort_passes
+    if _use_pallas_probes("dedup_rows"):
+        _note(paths, "hash_dedup_kernel")
+        keep = dedup_rows(bind, valid)
+        return jnp.where(keep[:, None], bind, -1), keep
+    _note(paths, "lexsort_dedup")
     keys = tuple(bind[:, v] for v in range(V - 1, -1, -1)) \
         + ((~valid).astype(jnp.int32),)
-    order = jnp.lexsort(keys)                  # invalid rows sort last
+    order = lexsort_passes(keys)               # invalid rows sort last
     bs, vs = bind[order], valid[order]
     dup = jnp.zeros((C,), bool).at[1:].set(
         jnp.all(bs[1:] == bs[:-1], axis=1) & vs[1:] & vs[:-1])
@@ -591,7 +658,8 @@ def _match_shard(s: jax.Array, p: jax.Array, o: jax.Array,
                  csr: Optional[Tuple[jax.Array, ...]] = None,
                  prop_windows: Optional[Dict[int, int]] = None,
                  route_ranks: Optional[Sequence[int]] = None,
-                 route_width: int = 0
+                 route_width: int = 0,
+                 paths: Optional[Dict[str, int]] = None
                  ) -> Tuple[jax.Array, jax.Array, List[int], jax.Array,
                             jax.Array, jax.Array]:
     """Match ``pattern`` over one shard's edge table, padded to
@@ -647,9 +715,11 @@ def _match_shard(s: jax.Array, p: jax.Array, o: jax.Array,
     per-step ``argsort`` or ``p == prop`` scan in the trace.  With
     ``csr=None`` the original masked-column builds are used
     (``local_match`` compatibility path, directly-built stores).
+
+    ``paths`` (a dict) receives the trace-time join-path counts
+    (``JOIN_PATHS``).
     """
-    from ..kernels.ops import compact_rows, fused_join, \
-        fused_join_supported
+    from ..kernels.ops import compact_rows, fused_join
     order = _connected_edge_order(pattern)
     edges = pattern.edges
     var_cols: List[int] = []
@@ -832,7 +902,7 @@ def _match_shard(s: jax.Array, p: jax.Array, o: jax.Array,
             gb = jax.lax.all_gather(bt, axis, tiled=True)
             gv = jax.lax.all_gather(vt, axis, tiled=True)
             shipped = gv.sum().astype(jnp.int32)   # rows on the wire
-            gb, gv = _dedup_padded(gb, gv)
+            gb, gv = _dedup_padded(gb, gv, paths)
             return gb, gv, shipped
 
         def ship_smaller_side(via_gather, via_edges):
@@ -873,7 +943,7 @@ def _match_shard(s: jax.Array, p: jax.Array, o: jax.Array,
                       else bt[:, col_idx(e.src)])
                 dv = (jnp.full((nr,), e.dst, jnp.int32) if e.dst >= 0
                       else bt[:, col_idx(e.dst)])
-                return vt & _probe_pair_member(sv, dv, t_s, t_o)
+                return vt & _probe_pair_member(sv, dv, t_s, t_o, paths)
 
             def pair_via_gather(bt, vt):
                 gb, gv, shipped = gathered_bindings(bt, vt)
@@ -933,22 +1003,23 @@ def _match_shard(s: jax.Array, p: jax.Array, o: jax.Array,
 
             def exp_via_gather(bt, vt):
                 # the fused Pallas kernel runs dedup -> expand -> filter
-                # in one VMEM pass over the raw gathered table; the
+                # in one SMEM pass over the raw gathered table; the
                 # composition below (exact-dedup then _expand_fixed) is
-                # both the off-TPU path and the semantics of record
+                # the default path and the semantics of record
                 gb = jax.lax.all_gather(bt, axis, tiled=True)
                 gv = jax.lax.all_gather(vt, axis, tiled=True)
                 shipped = gv.sum().astype(jnp.int32)
                 keys, payload = local_table()
-                if _use_pallas_probes() and fused_join_supported(
-                        gb.shape[0], gb.shape[1], keys.shape[0],
-                        capacity):
+                if _use_pallas_probes("fused_join"):
+                    _note(paths, "fused_join_kernel")
                     nb, nc, nv, over = fused_join(
                         gb, gv, probe_vals(gb), keys, payload, capacity)
                 else:
-                    gb, gv = _dedup_padded(gb, gv)
+                    _note(paths, "jnp_join")
+                    gb, gv = _dedup_padded(gb, gv, paths)
                     nb, nc, nv, over = _expand_fixed(
-                        gb, gv, probe_vals(gb), keys, payload, capacity)
+                        gb, gv, probe_vals(gb), keys, payload, capacity,
+                        paths)
                 return nb, nc, nv, over, shipped
 
             def exp_via_gather_c(bt, vt):
@@ -961,13 +1032,14 @@ def _match_shard(s: jax.Array, p: jax.Array, o: jax.Array,
                 gorder = jnp.argsort(gk)
                 nb, nc, nv, over = _expand_fixed(
                     bt, vt, probe_vals(bt), gk[gorder], gp[gorder],
-                    capacity)
+                    capacity, paths)
                 return nb, nc, nv, over, jnp.int32(sc.edge_rows), g_s, g_o
 
             if mode == "skip":
                 keys, payload = local_table()
                 bind, new_col, valid, over = _expand_fixed(
-                    bind, valid, probe_vals(bind), keys, payload, capacity)
+                    bind, valid, probe_vals(bind), keys, payload, capacity,
+                    paths)
                 dec_v, row_v = jnp.int32(COMM_SKIP), jnp.int32(0)
             elif mode == "gather":
                 bind, new_col, valid, over, shipped = exp_via_gather(
@@ -1007,23 +1079,6 @@ def local_match(s: jax.Array, p: jax.Array, o: jax.Array,
 # shard_map distributed execution
 # ----------------------------------------------------------------------
 
-def compat_shard_map(fn, mesh, in_specs, out_specs):
-    """shard_map across jax versions: top-level ``jax.shard_map`` with
-    ``check_vma`` (new), with ``check_rep`` (mid), or
-    ``jax.experimental.shard_map`` (jax < 0.5).  Replication checking is
-    off in all cases (manual collectives)."""
-    if hasattr(jax, "shard_map"):
-        try:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        except TypeError:
-            return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(fn, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=False)
-
-
 def make_spmd_matcher(mesh: Mesh, axis: str, pattern: QueryGraph,
                       capacity: int,
                       comm: Optional[Sequence[StepComm]] = None,
@@ -1031,7 +1086,8 @@ def make_spmd_matcher(mesh: Mesh, axis: str, pattern: QueryGraph,
                       use_csr: bool = False,
                       prop_windows: Optional[Dict[int, int]] = None,
                       route_ranks: Optional[Sequence[int]] = None,
-                      route_width: int = 0):
+                      route_width: int = 0,
+                      join_paths: Optional[Dict[str, int]] = None):
     """Build a jitted SPMD function: site-sharded (s,p,o) -> gathered
     binding tables (num_sites * capacity, V), validity mask, the
     per-device overflow row count (num_sites,), and the planner's
@@ -1062,6 +1118,9 @@ def make_spmd_matcher(mesh: Mesh, axis: str, pattern: QueryGraph,
     (``RoutePlan.seed_ranks`` / ``RoutePlan.width``) restrict the
     query to its route members and re-scope the striping to them (see
     ``_match_shard``).
+
+    ``join_paths`` (a dict) is filled with the join-path counts
+    (``JOIN_PATHS``) when the function is first traced.
     """
     # on a 1-device mesh the per-step gathers are identity and the
     # gathered dedup can never find anything (folded site groups are
@@ -1084,14 +1143,15 @@ def make_spmd_matcher(mesh: Mesh, axis: str, pattern: QueryGraph,
             s, p, o, pattern, capacity, axis=step_axis, comm=comm,
             axis_size=m, seed_decimate=seed_decimate, csr=csr,
             prop_windows=prop_windows, route_ranks=route_ranks,
-            route_width=route_width)
+            route_width=route_width, paths=join_paths)
         g_bind = jax.lax.all_gather(bind, axis, tiled=True)
         g_valid = jax.lax.all_gather(valid, axis, tiled=True)
         g_ovf = jax.lax.all_gather(ovf[None], axis, tiled=True)
         return g_bind, g_valid, g_ovf, dec, rows
 
-    fn = compat_shard_map(per_site, mesh, (P(axis, None),) * n_in,
-                          (P(), P(), P(), P(), P()))
+    fn = jax.shard_map(per_site, mesh=mesh,
+                       in_specs=(P(axis, None),) * n_in,
+                       out_specs=(P(), P(), P(), P(), P()), check_vma=False)
     return jax.jit(fn)
 
 
@@ -1229,7 +1289,8 @@ class SpmdEngine(EngineBase):
             folded[j % m].append(np.asarray(eids, np.int64))
         self.store = SiteStore.build(
             graph, [np.unique(np.concatenate(g)) if g
-                    else np.zeros(0, np.int64) for g in folded])
+                    else np.zeros(0, np.int64) for g in folded],
+            sharding=self._store_sharding())
         self.capacity = int(capacity)
         self.max_capacity = max(int(max_capacity) if max_capacity is not None
                                 else max(self.capacity, 1 << 20),
@@ -1247,6 +1308,9 @@ class SpmdEngine(EngineBase):
         # orders produce different binding-column orders and must not
         # share a compiled matcher) x capacity tier x store generation
         self._matchers: Dict[Tuple[Tuple, int, int], object] = {}
+        # per compiled matcher, the join paths its trace took
+        # (JOIN_PATHS; filled when the matcher first runs)
+        self._join_paths: Dict[Tuple[Tuple, int, int], Dict[str, int]] = {}
         # per-pattern static communication specs (planner output)
         self._comm_specs: Dict[Tuple, Tuple[StepComm, ...]] = {}
         # per-pattern seed-decimation decision (store + planner mode are
@@ -1284,6 +1348,11 @@ class SpmdEngine(EngineBase):
     @property
     def num_sites(self) -> int:
         return self.logical_sites
+
+    def _store_sharding(self) -> NamedSharding:
+        """Row-sharding of every ``SiteStore`` array over the mesh axis:
+        device ``j`` holds folded site group ``j``."""
+        return NamedSharding(self.mesh, P(self.axis, None))
 
     # ------------------------------------------------------------------
     def _route(self, pattern: QueryGraph) -> Optional[RoutePlan]:
@@ -1359,6 +1428,7 @@ class SpmdEngine(EngineBase):
             windows = ({e.prop: self.store.prop_window(e.prop)
                         for e in pattern.edges} if use_csr else None)
             route = self._route(pattern)
+            paths: Dict[str, int] = {}
             fn = make_spmd_matcher(self.mesh, self.axis, pattern, capacity,
                                    comm=self._comm_spec(pattern),
                                    seed_decimate=self._seed_decimation(
@@ -1369,8 +1439,10 @@ class SpmdEngine(EngineBase):
                                                 else None),
                                    route_width=(route.width
                                                 if route is not None
-                                                else 0))
+                                                else 0),
+                                   join_paths=paths)
             self._matchers[key] = fn
+            self._join_paths[key] = paths
             self._compiles += 1
         return fn
 
@@ -1643,7 +1715,8 @@ class SpmdEngine(EngineBase):
             folded[j % m].append(np.asarray(eids, np.int64))
         store = SiteStore.build(
             self.graph, [np.unique(np.concatenate(g)) if g
-                         else np.zeros(0, np.int64) for g in folded])
+                         else np.zeros(0, np.int64) for g in folded],
+            sharding=self._store_sharding())
         # install: everything planned against the old store's residency
         # (routes, comm specs, seed decimation, capacity hints) is
         # invalid for the new placement
@@ -1673,13 +1746,25 @@ class SpmdEngine(EngineBase):
         return route.members if route is not None else None
 
     def _stats_extra(self) -> Dict[str, float]:
-        return {"compiled_shapes": float(self._compiles),
+        from ..kernels.ops import _interpret_default
+        kernels = sum(_use_pallas_probes(k) for k in MATCH_KERNELS)
+        # join paths summed over every matcher traced so far: which
+        # kernels the engine's programs contain, and which steps took
+        # the jnp composition
+        traced = {f"traced_{name}": 0.0 for name in JOIN_PATHS}
+        for paths in self._join_paths.values():
+            for name, n in paths.items():
+                traced[f"traced_{name}"] += n
+        return {**traced,
+                "pallas_interpret": float(kernels > 0
+                                          and _interpret_default(None)),
+                "compiled_shapes": float(self._compiles),
                 "store_generation": float(self._store_gen),
                 "devices": float(self.store.num_sites),
                 "comm_planner": float(self.comm_plan),
                 "routing": float(bool(self.routing and self.comm_plan
                                       and self.store.num_sites > 1)),
                 "replicated_props": float(len(self.replicated_props)),
-                "pallas_join_kernels": float(_use_pallas_probes()),
+                "pallas_join_kernels": float(kernels),
                 "csr_prop_tables": float(
                     self.store.csr_arrays() is not None)}

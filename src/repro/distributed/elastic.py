@@ -85,11 +85,11 @@ class ElasticMeshManager:
     def make_mesh(self):
         import jax
 
-        from ..launch.mesh import _axis_types_kw
         plan = self.current_plan()
         dev = np.asarray(self.live[: plan.devices_used]).reshape(plan.shape)
-        return jax.sharding.Mesh(dev, plan.axes,
-                                 **_axis_types_kw(jax, len(plan.axes)))
+        return jax.sharding.Mesh(
+            dev, plan.axes,
+            axis_types=(jax.sharding.AxisType.Auto,) * len(plan.axes))
 
     def reshard(self, tree: Any, shardings: Any) -> Any:
         """Re-place a (restored) pytree onto the current mesh."""

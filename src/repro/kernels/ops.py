@@ -7,7 +7,7 @@ un-pads the result.  ``ref.py`` holds the oracles.
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -23,13 +23,6 @@ from .semijoin import (BM, BN, dedup_blocks, fused_join_blocks,
 #: never equals a real vertex id (ids are bounded by ``MAX_VERTEX_ID``,
 #: enforced at ``RDFGraph`` construction).
 INT32_MAX = INT32_SENTINEL
-
-#: VMEM working-set budget for the single-pass dedup / fused-join
-#: kernels (whole binding table + hash slots + outputs resident at
-#: once).  Half the ~16 MB per-core budget leaves room for double
-#: buffering; bigger shapes fall back to the jnp oracles.
-KERNEL_VMEM_BUDGET = 8 * 1024 * 1024
-
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
@@ -78,6 +71,21 @@ def compact_rows(sel: jax.Array, cols: Tuple[jax.Array, ...], size: int,
                  for c in cols), ok
 
 
+def lexsort_passes(keys: Sequence[jax.Array]) -> jax.Array:
+    """The permutation of ``jnp.lexsort(keys)`` (last key primary, ties
+    in input order), built as one stable single-key sort per key, least
+    significant first -- a stable LSD sort is lexicographic.  The TPU
+    compiler's time for one sort grows steeply with its operand count:
+    for a described v5e at 64k rows, about 20 s for a one-key sort
+    against 130-160 s for a three-key one, so k passes of two operands
+    each compile several times faster than one sort of k + 1."""
+    perm = jnp.arange(keys[0].shape[0], dtype=jnp.int32)
+    for key in keys:
+        _, perm = jax.lax.sort((key[perm], perm), num_keys=1,
+                               is_stable=True)
+    return perm
+
+
 def _block_plan_1d(qs_p: jax.Array, ts_p: jax.Array, bm: int, bn: int,
                    jit_safe: bool):
     """Block plan on one sorted+padded key column: first overlapping
@@ -119,8 +127,8 @@ def _prep_blocks(queries: jax.Array, table_sorted: jax.Array,
     nqb = qs_p.shape[0] // bm
     ntb = ts_p.shape[0] // bn
     lo, widths, width = _block_plan_1d(qs_p, ts_p, bm, bn, jit_safe)
-    return (order, qs_p.reshape(nqb, bm), ts_p.reshape(ntb, bn), lo, widths,
-            width, nq)
+    return (order, qs_p.reshape(nqb, 1, bm), ts_p.reshape(ntb, 1, bn), lo,
+            widths, width, nq)
 
 
 def semijoin(queries: jax.Array, table_sorted: jax.Array,
@@ -134,9 +142,9 @@ def semijoin(queries: jax.Array, table_sorted: jax.Array,
     table_sorted = table_sorted.astype(jnp.int32)
     if queries.shape[0] == 0 or table_sorted.shape[0] == 0:
         return jnp.zeros(queries.shape, dtype=bool)
-    order, q2d, t2d, lo, widths, width, nq = _prep_blocks(
+    order, q3d, t3d, lo, widths, width, nq = _prep_blocks(
         queries, table_sorted, bm, bn, jit_safe=jit_safe)
-    got = semijoin_blocks(q2d, t2d, lo, widths, width, count=False,
+    got = semijoin_blocks(q3d, t3d, lo, widths, width, count=False,
                           interpret=_interpret_default(interpret))
     mask_sorted = got.reshape(-1)[:nq] > 0
     inv = jnp.zeros_like(order).at[order].set(jnp.arange(nq))
@@ -154,9 +162,9 @@ def join_count(queries: jax.Array, table_sorted: jax.Array,
     table_sorted = table_sorted.astype(jnp.int32)
     if queries.shape[0] == 0 or table_sorted.shape[0] == 0:
         return jnp.zeros(queries.shape, dtype=jnp.int32)
-    order, q2d, t2d, lo, widths, width, nq = _prep_blocks(
+    order, q3d, t3d, lo, widths, width, nq = _prep_blocks(
         queries, table_sorted, bm, bn, jit_safe=jit_safe)
-    got = semijoin_blocks(q2d, t2d, lo, widths, width, count=True,
+    got = semijoin_blocks(q3d, t3d, lo, widths, width, count=True,
                           interpret=_interpret_default(interpret))
     cnt_sorted = got.reshape(-1)[:nq]
     inv = jnp.zeros_like(order).at[order].set(jnp.arange(nq))
@@ -180,17 +188,17 @@ def pair_semijoin(q_s: jax.Array, q_o: jax.Array,
     t_s, t_o = t_s.astype(jnp.int32), t_o.astype(jnp.int32)
     if q_s.shape[0] == 0 or t_s.shape[0] == 0:
         return jnp.zeros(q_s.shape, dtype=bool)
-    torder = jnp.lexsort((t_o, t_s))
+    torder = lexsort_passes((t_o, t_s))
     ts, to = _pad_tail(t_s[torder], bn), _pad_tail(t_o[torder], bn)
-    qorder = jnp.lexsort((q_o, q_s))
+    qorder = lexsort_passes((q_o, q_s))
     qs, qo = _pad_tail(q_s[qorder], bm), _pad_tail(q_o[qorder], bm)
     nq = q_s.shape[0]
     nqb, ntb = qs.shape[0] // bm, ts.shape[0] // bn
     # plan on the subject column alone: both sides lexsorted by (s, o),
     # so a query block's candidate table rows lie in its subject span
     lo, widths, width = _block_plan_1d(qs, ts, bm, bn, jit_safe)
-    got = pair_semijoin_blocks(qs.reshape(nqb, bm), qo.reshape(nqb, bm),
-                               ts.reshape(ntb, bn), to.reshape(ntb, bn),
+    got = pair_semijoin_blocks(qs.reshape(nqb, 1, bm), qo.reshape(nqb, 1, bm),
+                               ts.reshape(ntb, 1, bn), to.reshape(ntb, 1, bn),
                                lo, widths, width,
                                interpret=_interpret_default(interpret))
     mask_sorted = got.reshape(-1)[:nq] > 0
@@ -212,23 +220,8 @@ def _hash_size(C: int) -> int:
     return H
 
 
-def dedup_rows_supported(C: int, V: int) -> bool:
-    """Static guard: does the hash-dedup kernel's working set (binding
-    table + hash slots + keep mask, all int32) fit the VMEM budget?
-    V == 0 tables carry no values to compare and stay on the oracle."""
-    if V <= 0 or C <= 0:
-        return False
-    return (C * (V + 2) + _hash_size(C)) * 4 <= KERNEL_VMEM_BUDGET
-
-
-def fused_join_supported(C: int, V: int, T: int, capacity: int) -> bool:
-    """Static guard for the fused join kernel: dedup working set plus
-    the edge table (keys + payload) and the capacity-row outputs."""
-    if not dedup_rows_supported(C, V):
-        return False
-    working = (C * (V + 3) + _hash_size(C) + 2 * T
-               + capacity * (V + 2))
-    return working * 4 <= KERNEL_VMEM_BUDGET
+def _column_major(bind: jax.Array) -> jax.Array:
+    return bind.astype(jnp.int32).T.reshape(-1)
 
 
 def dedup_rows(bind: jax.Array, valid: jax.Array,
@@ -238,13 +231,13 @@ def dedup_rows(bind: jax.Array, valid: jax.Array,
     valid row equals row ``i``.  Exact (open-addressed int32 hash with
     full-row compare on collision) and in place -- unlike the lexsort
     oracle it never reorders rows, which no caller depends on anyway.
-    Callers must check ``dedup_rows_supported`` first."""
+    Every operand lives in SMEM (1 MiB on a v5e core), so on a chip the
+    table has to fit there."""
     C, V = bind.shape
-    keep = dedup_blocks(bind.astype(jnp.int32),
-                        valid.astype(jnp.int32).reshape(1, C),
-                        _hash_size(C),
+    keep = dedup_blocks(_column_major(bind), valid.astype(jnp.int32),
+                        C, V, _hash_size(C),
                         interpret=_interpret_default(interpret))
-    return keep[0] > 0
+    return keep > 0
 
 
 def fused_join(bind: jax.Array, valid: jax.Array, probe: jax.Array,
@@ -259,17 +252,15 @@ def fused_join(bind: jax.Array, valid: jax.Array, probe: jax.Array,
     overflow counts result rows that did not fit (identical to the
     composition's count, including the int32 cumsum wrap-risk guard);
     output row *placement* differs (original gathered order, not
-    lexsorted), which no caller observes.  Callers must check
-    ``fused_join_supported`` first."""
+    lexsorted), which no caller observes.  Every operand and output
+    lives in SMEM, as for ``dedup_rows``."""
     C, V = bind.shape
     nb, nc, nv, over = fused_join_blocks(
-        bind.astype(jnp.int32), valid.astype(jnp.int32).reshape(1, C),
-        probe.astype(jnp.int32).reshape(1, C),
-        keys_sorted.astype(jnp.int32).reshape(1, -1),
-        payload.astype(jnp.int32).reshape(1, -1),
-        capacity, _hash_size(C),
+        _column_major(bind), valid.astype(jnp.int32),
+        probe.astype(jnp.int32), keys_sorted.astype(jnp.int32),
+        payload.astype(jnp.int32), C, V, capacity, _hash_size(C),
         interpret=_interpret_default(interpret))
-    return nb, nc[0], nv[0] > 0, over[0, 0]
+    return nb.reshape(V, capacity).T, nc, nv > 0, over[0]
 
 
 # ----------------------------------------------------------------------

@@ -10,15 +10,6 @@ from typing import Optional, Tuple
 import numpy as np
 
 
-def _axis_types_kw(jax, n_axes: int) -> dict:
-    """``axis_types=(Auto, ...)`` where the jax version has it; older
-    jax (< 0.5) has no AxisType and defaults to Auto anyway."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 multi-pod (512 chips).
 
@@ -36,7 +27,8 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"set XLA_FLAGS=--xla_force_host_platform_device_count={n} "
             f"BEFORE importing jax (see launch/dryrun.py)")
     dev = np.asarray(devices[:n]).reshape(shape)
-    return jax.sharding.Mesh(dev, axes, **_axis_types_kw(jax, len(axes)))
+    return jax.sharding.Mesh(
+        dev, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(num_sites: int = 1, axis: str = "sites"):
@@ -44,7 +36,7 @@ def make_host_mesh(num_sites: int = 1, axis: str = "sites"):
     import jax
     devices = jax.devices()[:num_sites]
     return jax.sharding.Mesh(np.asarray(devices), (axis,),
-                             **_axis_types_kw(jax, 1))
+                             axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def mesh_axis_sizes(mesh) -> dict:

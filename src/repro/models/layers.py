@@ -378,12 +378,11 @@ def _moe_shard_map(cfg: ModelConfig, p, x: jax.Array, C: int,
             aux = jax.lax.pmean(aux, dp_axes)
         return y, aux
 
-    from ..core.spmd import compat_shard_map
-    fn = compat_shard_map(
-        local_fn, mesh,
-        (batch_spec, w_specs["router"], w_specs["w1"],
-         w_specs["w3"], w_specs["w2"]),
-        (batch_spec, P()))
+    fn = jax.shard_map(
+        local_fn, mesh=mesh,
+        in_specs=(batch_spec, w_specs["router"], w_specs["w1"],
+                  w_specs["w3"], w_specs["w2"]),
+        out_specs=(batch_spec, P()), check_vma=False)
     return fn(x, p["router"], p["w1"], p["w3"], p["w2"])
 
 

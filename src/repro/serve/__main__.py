@@ -84,8 +84,11 @@ def main(argv=None) -> int:
                                   snapshot, validate_snapshot)
     from repro.obs.metrics import MetricsRegistry
     from repro.obs.trace import Tracer
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.serve import FrontDoor, FrontDoorConfig, measure_capacity
 
+    print(f"[repro.serve] compile cache: {enable_compile_cache()}",
+          file=sys.stderr)
     t_start = time.perf_counter()
     print("[repro.serve] building plan + SPMD session", file=sys.stderr)
     g = generate_watdiv(args.triples, seed=1)

@@ -102,6 +102,48 @@ def test_truncation_flag():
     assert res.truncated and res.num_rows == 3
 
 
+def test_hub_join_truncates_before_materializing():
+    """A join through one hub vertex (n in-edges x n out-edges) would
+    expand to n^2 rows; with ``max_rows`` the expansion builds only the
+    first max_rows of them -- the same rows a full expansion cut to
+    ``max_rows`` keeps -- and flags the truncation."""
+    n, hub = 400, 0
+    s = np.concatenate([np.arange(1, n + 1), np.full(n, hub)])
+    p = np.concatenate([np.zeros(n), np.ones(n)])
+    o = np.concatenate([np.full(n, hub), np.arange(n + 1, 2 * n + 1)])
+    g = RDFGraph(s.astype(np.int32), p.astype(np.int32), o.astype(np.int32),
+                 2 * n + 1, 2)
+    pat = QueryGraph.make([(V(0), V(1), 0), (V(1), V(2), 1)])
+    full = match_pattern(g, pat)
+    assert full.num_rows == n * n and not full.truncated
+    cut = match_pattern(g, pat, max_rows=1000)
+    assert cut.truncated and cut.num_rows == 1000
+    np.testing.assert_array_equal(cut.rows(), full.rows()[:1000])
+
+
+@pytest.mark.parametrize("far", ["object", "subject"])
+def test_hub_join_to_a_constant_stays_exact(far):
+    """The same hub, but one end of the two-edge path is a constant: the
+    answer (n rows) fits under ``max_rows`` though the unconstrained hub
+    join (n^2 rows) does not, and it comes back exact and untruncated --
+    a constant end is a filter on the rows kept, never a cut before it."""
+    n, hub = 400, 0
+    s = np.concatenate([np.arange(1, n + 1), np.full(n, hub)])
+    p = np.concatenate([np.zeros(n), np.ones(n)])
+    o = np.concatenate([np.full(n, hub), np.arange(n + 1, 2 * n + 1)])
+    g = RDFGraph(s.astype(np.int32), p.astype(np.int32), o.astype(np.int32),
+                 2 * n + 1, 2)
+    if far == "object":     # ?a -0-> ?h -1-> y_7
+        pat = QueryGraph.make([(V(0), V(1), 0), (V(1), n + 7, 1)])
+        want = {(hub, a) for a in range(1, n + 1)}   # (?h, ?a)
+    else:                   # x_7 -0-> ?h -1-> ?b
+        pat = QueryGraph.make([(7, V(1), 0), (V(1), V(2), 1)])
+        want = {(b, hub) for b in range(n + 1, 2 * n + 1)}   # (?b, ?h)
+    res = match_pattern(g, pat, max_rows=1000)
+    assert not res.truncated and res.num_rows == n
+    assert {tuple(r) for r in res.rows().tolist()} == want
+
+
 # ----------------------------------------------------------------------
 # Sentinel-safety guard: the id-space bound shared with the SPMD /
 # kernel layers (repro.constants).  Ids at the 2^21-1 bound stay far
