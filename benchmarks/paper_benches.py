@@ -360,100 +360,6 @@ def bench_spmd_routing() -> None:
 
 
 # ----------------------------------------------------------------------
-# Telemetry-layer latency bench: per-backend, per-shape wall-clock
-# latency through the obs histograms (p50/p99 derived from the same
-# fixed-bucket counts a metrics snapshot exports), plus queries/sec.
-# The SPMD backend runs under an explicit enabled tracer and the bench
-# closes with a trace/ledger reconciliation row: the sum of per-step
-# traced bytes over every root span must equal the engine's cumulative
-# ``comm_bytes`` ledger exactly (`trace_ledger_delta_bytes` == 0).
-# ----------------------------------------------------------------------
-
-def bench_latency() -> None:
-    from repro.obs.metrics import MetricsRegistry
-    from repro.obs.trace import Tracer
-
-    g, wl = _setup(n_triples=8_000, n_queries=500, seed=5)
-    plan = build_plan(g, wl, PartitionConfig(kind="vertical", num_sites=4))
-    registry = MetricsRegistry()
-    tracer = Tracer(enabled=True, capacity=4096)
-    shapes = _shape_workload(g)
-    for backend in BACKENDS:
-        sess = Session(plan, backend=backend, tracer=tracer,
-                       metrics_registry=registry)
-        n_total = 0
-        wall_total = 0.0
-        for shape, qs in shapes.items():
-            h = registry.histogram("repro_bench_latency_seconds",
-                                   backend=backend, shape=shape)
-            # one warm-up query so the SPMD numbers measure steady-state
-            # serving, not jit compilation (harmless no-op elsewhere)
-            sess.execute(qs[0])
-            t0 = time.perf_counter()
-            for q in qs:
-                q0 = time.perf_counter()
-                sess.execute(q)
-                h.observe(time.perf_counter() - q0)
-            dt = time.perf_counter() - t0
-            n_total += len(qs)
-            wall_total += dt
-            emit("bench_latency", f"{backend}_{shape}", "p50_ms",
-                 h.percentile(0.50) * 1e3)
-            emit("bench_latency", f"{backend}_{shape}", "p99_ms",
-                 h.percentile(0.99) * 1e3)
-            emit("bench_latency", f"{backend}_{shape}", "qps",
-                 len(qs) / max(dt, 1e-12))
-        emit("bench_latency", backend, "qps",
-             n_total / max(wall_total, 1e-12))
-        if backend == "spmd":
-            spans = [s for s in tracer.store.spans()
-                     if s.attrs.get("backend") == "spmd"]
-            traced = sum(rec.get("bytes", 0)
-                         for s in spans for rec in s.records)
-            ledger = sess.stats().comm_bytes
-            emit("bench_latency", "spmd", "trace_ledger_delta_bytes",
-                 float(abs(traced - ledger)))
-            _write_latency_reports(spans)
-
-
-def _write_latency_reports(spans) -> None:
-    """Persist the per-join-step roofline report (from the SPMD
-    ``comm_step`` trace records gathered by ``bench_latency``) and a
-    ``repro.bench/v1`` latency record next to the other bench
-    artifacts (reports/).  Best-effort: a read-only checkout skips."""
-    import json
-    import subprocess
-    import sys
-    from pathlib import Path
-    sys.path.insert(0, str(Path(__file__).parent))
-    from roofline import join_step_report
-    try:
-        out = Path(__file__).parent.parent / "reports"
-        out.mkdir(parents=True, exist_ok=True)
-        report = join_step_report(spans)
-        (out / "join_roofline.json").write_text(
-            json.dumps(report, indent=2, sort_keys=True))
-        try:
-            rev = subprocess.run(["git", "rev-parse", "--short", "HEAD"],
-                                 capture_output=True, text=True,
-                                 timeout=10).stdout.strip() or None
-        except Exception:
-            rev = None
-        latency_rows = [
-            {"bench": b, "variant": v, "metric": m, "value": val}
-            for (b, v, m, val) in ROWS if b == "bench_latency"]
-        (out / "latency.json").write_text(json.dumps({
-            "schema": "repro.bench/v1", "git_rev": rev,
-            "rows": latency_rows,
-            "join_roofline": report["totals"]},
-            indent=2, sort_keys=True))
-        emit("bench_latency", "spmd", "join_roofline_bytes",
-             float(report["totals"]["bytes"]))
-    except OSError:
-        pass
-
-
-# ----------------------------------------------------------------------
 # Serving front door (repro.serve): three claims on one seeded
 # star/chain/cycle workload.  (1) Parity -- answers through the full
 # admission -> micro-batch -> dispatch path are set-identical to direct
@@ -536,6 +442,6 @@ def bench_serve() -> None:
 ALL = [bench_minsup, bench_throughput, bench_response, bench_scalability,
        bench_redundancy, bench_offline, bench_queries, bench_engine_parity,
        bench_spmd_comm, bench_spmd_replication, bench_spmd_routing,
-       bench_latency, bench_serve]
+       bench_serve]
 
-SMOKE = [bench_engine_parity, bench_spmd_routing, bench_latency]
+SMOKE = [bench_engine_parity, bench_spmd_routing]

@@ -14,17 +14,13 @@ the table grows or an override is applied (``constants_for``).
 Also reports MODEL_FLOPS (6*N*D dense / 6*N_active*D MoE; 2*N*D for
 prefill; 2*N_active*B per decode step) and the useful-compute ratio
 MODEL_FLOPS / HLO_FLOPs, which exposes remat/redundancy waste.
-
-``join_step_report`` is the SPMD-side counterpart: it folds the
-per-join-step ``comm_step`` trace records (src/repro/core/spmd.py)
-into an achieved-vs-roofline bytes report per (step, prop, decision).
 """
 from __future__ import annotations
 
 import dataclasses
 import json
 from pathlib import Path
-from typing import Any, Dict, Iterable, List, Optional
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
@@ -205,79 +201,3 @@ def bench_roofline(report_dir: str = "reports/dryrun_baseline",
         print(f"roofline,{tag},collective_s,{r.collective_s:.6g}")
         print(f"roofline,{tag},dominant,{r.dominant}")
         print(f"roofline,{tag},roofline_fraction,{r.roofline_fraction:.4f}")
-
-
-# ----------------------------------------------------------------------
-# SPMD per-join-step achieved-vs-roofline report (from comm_step
-# trace records -- see src/repro/core/spmd.py ledger/trace emission)
-# ----------------------------------------------------------------------
-
-def _walk_spans(spans: Iterable[Any]) -> Iterable[Any]:
-    """Yield every span (depth-first) from a mix of ``Span`` objects
-    and flat ``spans.jsonl`` dicts."""
-    for s in spans:
-        if hasattr(s, "walk"):
-            yield from s.walk()
-        else:
-            yield s
-
-
-def join_step_report(spans: Iterable[Any],
-                     hw: Optional[HardwareConstants] = None,
-                     backend: Optional[str] = None) -> Dict[str, Any]:
-    """Fold ``comm_step`` records out of finished spans into a
-    per-(step, prop, decision) achieved-vs-roofline bytes report.
-
-    ``spans`` may be ``Tracer.store.spans()`` (Span objects, children
-    walked) or rows loaded from ``spans.jsonl`` (flat dicts).  Wall
-    time is the summed duration of spans that directly carry at least
-    one ``comm_step`` record, so the achieved rate reflects end-to-end
-    query time, not just the shipping fraction.  The report is tagged
-    with the hardware-constants row used for the roofline bound."""
-    hw = hw or constants_for(backend)
-    groups: Dict[tuple, Dict[str, float]] = {}
-    total_bytes = 0
-    total_rows = 0
-    wall_s = 0.0
-    n_records = 0
-    for sp in _walk_spans(spans):
-        recs = sp.get("records") if isinstance(sp, dict) else sp.records
-        comm = [r for r in (recs or []) if r.get("kind") == "comm_step"]
-        if not comm:
-            continue
-        dur = (sp.get("duration") if isinstance(sp, dict)
-               else sp.duration) or 0.0
-        wall_s += float(dur)
-        for r in comm:
-            key = (int(r.get("step", -1)), int(r.get("prop", -1)),
-                   str(r.get("decision", "?")))
-            g = groups.setdefault(key, {"bytes": 0, "rows": 0, "records": 0})
-            g["bytes"] += int(r.get("bytes", 0))
-            g["rows"] += int(r.get("rows", 0))
-            g["records"] += 1
-            total_bytes += int(r.get("bytes", 0))
-            total_rows += int(r.get("rows", 0))
-            n_records += 1
-    steps = []
-    for (step, prop, decision), g in sorted(groups.items()):
-        steps.append({
-            "step": step, "prop": prop, "decision": decision,
-            "bytes": int(g["bytes"]), "rows": int(g["rows"]),
-            "records": int(g["records"]),
-            "bytes_per_row": (g["bytes"] / g["rows"]) if g["rows"] else 0.0,
-            "bytes_share": (g["bytes"] / total_bytes) if total_bytes else 0.0,
-            "ici_roofline_s": g["bytes"] / hw.ici_bw,
-        })
-    roofline_s = total_bytes / hw.ici_bw
-    return {
-        "schema": "repro.roofline_join/v1",
-        "constants": hw.as_dict(),
-        "totals": {
-            "bytes": int(total_bytes), "rows": int(total_rows),
-            "records": int(n_records), "wall_s": wall_s,
-            "achieved_bytes_per_s": (total_bytes / wall_s) if wall_s else 0.0,
-            "ici_roofline_s": roofline_s,
-            "ici_fraction": (roofline_s / wall_s) if wall_s else 0.0,
-        },
-        "steps": steps,
-    }

@@ -52,8 +52,8 @@ def main() -> None:
     ap.add_argument("--skip-roofline", action="store_true")
     ap.add_argument("--smoke", action="store_true",
                     help="quick CI subset (engine-parity regression bench "
-                         "+ telemetry latency bench + plan-lifecycle "
-                         "bench); implies --skip-roofline")
+                         "+ routing ledger bench + plan-lifecycle bench); "
+                         "implies --skip-roofline")
     ap.add_argument("--trace", action="store_true",
                     help="enable the process-default span tracer for "
                          "every bench engine")

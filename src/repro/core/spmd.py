@@ -87,6 +87,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..constants import INT32_SENTINEL
 from ..kernels import ref as kref
+from ..obs.trace import NULL_SPAN
 from .engine import EngineBase
 from .executor import CostModel, ExecStats, QueryResult
 from .fragmentation import Fragmentation
@@ -706,7 +707,9 @@ def _match_shard(s: jax.Array, p: jax.Array, o: jax.Array,
 
     jit-friendly: static pattern, static capacity, static per-step
     specs; overflow (result rows beyond capacity at any step) is
-    counted, not silently dropped.
+    counted, not silently dropped.  Step ``j`` (0 is the seed) traces
+    under ``jax.named_scope(f"step{j}")``, so its operations carry the
+    step in their HLO metadata and in a profiler trace.
 
     ``csr`` (the ``SiteStore.csr_arrays()`` tuple, per-device slices)
     plus ``prop_windows`` (static per-property window rows,
@@ -779,286 +782,288 @@ def _match_shard(s: jax.Array, p: jax.Array, o: jax.Array,
     edge_cache: Dict[int, Tuple[jax.Array, jax.Array, jax.Array]] = {}
 
     for step, ei in enumerate(order):
-        e = edges[ei]
-        s_known = e.src >= 0 or e.src in var_cols
-        d_known = e.dst >= 0 or e.dst in var_cols
+        with jax.named_scope(f"step{step}"):
+            e = edges[ei]
+            s_known = e.src >= 0 or e.src in var_cols
+            d_known = e.dst >= 0 or e.dst in var_cols
 
-        if step == 0:
-            # initialize from the property's local edge list.  With CSR
-            # tables the candidate rows are the property's packed run (a
-            # static window, identically (s, o)-ordered on every device
-            # -- the same order the (p, s, o)-sorted fallback scan
-            # yields, so seed decimation stripes identically); without
-            # them, scan the full padded columns.
-            if csr is not None:
-                seed_s, seed_o, n_live = csr_window(e.prop, True)
-                live = jnp.arange(seed_s.shape[0], dtype=jnp.int32) \
-                    < n_live
-            else:
-                seed_s, seed_o, live = s, o, (p == e.prop)
-            sel = live
-            if e.src >= 0:
-                sel &= seed_s == e.src
-            if e.dst >= 0:
-                sel &= seed_o == e.dst
-            if e.src < 0 and e.src == e.dst:
-                sel &= seed_s == seed_o
-            if route_ranks is not None and axis is not None:
-                # routed execution: devices outside the route never
-                # seed (rank -1), so they hold zero valid rows for the
-                # whole query; with decimation the members additionally
-                # stripe the (route-complete, identically-ordered) seed
-                # list among themselves in rendezvous-rank order
-                my_rank = jnp.asarray(
-                    list(route_ranks),
-                    jnp.int32)[jax.lax.axis_index(axis)]
-                if seed_decimate:
-                    rank = jnp.cumsum(sel) - 1
-                    sel &= (rank % max(route_width, 1)) == my_rank
-                else:
-                    sel &= my_rank >= 0
-            elif seed_decimate and axis is not None:
-                # step 0's property is shard-complete: every device sees
-                # the identical, identically-ordered seed list, so each
-                # keeping every m-th row partitions the seeds exactly
-                # (balanced work, no cross-device duplicates, no m-fold
-                # blowup of downstream binding counts)
-                rank = jnp.cumsum(sel) - 1
-                sel &= (rank % axis_size) == jax.lax.axis_index(axis)
-            (s_col, o_col), valid = compact_rows(sel, (seed_s, seed_o),
-                                                 capacity, fill=-1)
-            ovf = jnp.maximum(
-                ovf, sel.sum().astype(jnp.int32) - capacity)
-            cols = []
-            if e.src < 0:
-                var_cols.append(e.src)
-                cols.append(s_col)
-            if e.dst < 0 and e.dst != e.src:
-                var_cols.append(e.dst)
-                cols.append(o_col)
-            bind = (jnp.stack(cols, axis=1) if cols
-                    else jnp.zeros((capacity, 0), jnp.int32)).astype(jnp.int32)
-            continue
-
-        sc = comm[step - 1] if comm is not None else None
-        mode = ("skip" if axis is None
-                else sc.mode if sc is not None else "gather")
-        n_in = len(var_cols)          # binding columns entering the step
-
-        # cross-step cache state for this step's property ("dynamic"
-        # steps only: "skip" never gathers, "gather" never ships edges)
-        cache = edge_cache.get(e.prop) if mode == "dynamic" else None
-        have0 = cache[2] if cache is not None else jnp.bool_(False)
-
-        # -- shared builders for this step (all shapes static) ----------
-        def local_pair_tables():
-            if csr is not None:
-                t_s, t_o, _n = csr_window(e.prop, True, pay_fill=imax)
-                return t_s, t_o
-            sel_ = p == e.prop
-            return jnp.where(sel_, s, imax), jnp.where(sel_, o, imax)
-
-        def fresh_prop_tables():
-            # the edge-shipping side: this device's OWNED rows of the
-            # property, compacted into the static ship buffer
-            # (sc.gather_cap == SiteStore.prop_ship_window) and
-            # gathered from every device.  Ownership (exactly one
-            # device per resident edge, see SiteStore) makes the
-            # gathered table each resident edge exactly once: valid
-            # rows on the wire, not the padded window, and no
-            # replicated duplicates to re-expand.  Compacting a
-            # subsequence of the (s, o)-sorted run keeps it sorted;
-            # the imax fill sorts last, as before.
-            if csr is not None:
-                fk, fp, n_run = csr_window(e.prop, True, pay_fill=imax)
-                ow = owned_run_window(e.prop, fk.shape[0], n_run)
-                (ls, lo_), _ = compact_rows(ow, (fk, fp), sc.gather_cap)
-            else:
-                (ls, lo_), _ = compact_rows(p == e.prop, (s, o),
-                                            sc.gather_cap)
-            return (jax.lax.all_gather(ls, axis, tiled=True),
-                    jax.lax.all_gather(lo_, axis, tiled=True))
-
-        def gathered_prop_tables():
-            # reuse an earlier step's gather of the same property when
-            # this trace already holds one; gather fresh otherwise
-            if cache is None:
-                return fresh_prop_tables()
-            return jax.lax.cond(have0, lambda: (cache[0], cache[1]),
-                                fresh_prop_tables)
-
-        def carry_prop_tables():
-            # equal-shape stand-ins the binding-gather branch returns so
-            # both lax.cond branches agree; an incumbent cache entry is
-            # carried through unchanged (stand-ins are only ever stored
-            # with have=False and never read back as tables)
-            if cache is not None:
-                return cache[0], cache[1]
-            rows_ = axis_size * sc.gather_cap
-            return (jnp.full((rows_,), imax, jnp.int32),
-                    jnp.full((rows_,), imax, jnp.int32))
-
-        def gathered_bindings(bt, vt):
-            gb = jax.lax.all_gather(bt, axis, tiled=True)
-            gv = jax.lax.all_gather(vt, axis, tiled=True)
-            shipped = gv.sum().astype(jnp.int32)   # rows on the wire
-            gb, gv = _dedup_padded(gb, gv, paths)
-            return gb, gv, shipped
-
-        def ship_smaller_side(via_gather, via_edges):
-            # dynamic decision: psum the live global binding count and
-            # run the cheaper branch.  Cost comparison in float32:
-            # n_glob * row_bytes can exceed int32 on big meshes, and
-            # edge_bytes can exceed int32 as a trace-time constant;
-            # mantissa rounding is harmless for a heuristic.  The byte
-            # formulas are the ledger's (bind_row_bytes / edge_bytes),
-            # so decision and accounting cannot diverge.  Both branches
-            # return the (possibly stand-in) global edge tables last, so
-            # the cross-step cache survives the cond; a cached table
-            # makes the edge side free (COMM_EDGE_CACHED, zero bytes),
-            # which the predicate accounts for.
-            n_glob = jax.lax.psum(valid.sum().astype(jnp.int32), axis)
-            gather_cost = n_glob.astype(jnp.float32) \
-                * float(bind_row_bytes(n_in))
-            edge_cost = jnp.where(have0, jnp.float32(0.0),
-                                  jnp.float32(sc.edge_bytes))
-            pred = gather_cost <= edge_cost
-            out = jax.lax.cond(pred, via_gather, via_edges, bind, valid)
-            *res, c_ts, c_to = out
-            edge_cache[e.prop] = (c_ts, c_to, have0 | ~pred)
-            dec = jnp.where(
-                pred, COMM_GATHER,
-                jnp.where(have0, COMM_EDGE_CACHED, COMM_EDGE)
-            ).astype(jnp.int32)
-            return tuple(res), dec, n_glob
-
-        if s_known and d_known:
-            # cycle close: membership of the bound (src, dst) pair among
-            # the property's edges.  Sentinel table rows (INT32_MAX,
-            # INT32_MAX) never equal a real id pair; invalid probe rows
-            # are masked via ``vt``.
-            def pair_keep(bt, vt, t_s, t_o):
-                nr = bt.shape[0]
-                sv = (jnp.full((nr,), e.src, jnp.int32) if e.src >= 0
-                      else bt[:, col_idx(e.src)])
-                dv = (jnp.full((nr,), e.dst, jnp.int32) if e.dst >= 0
-                      else bt[:, col_idx(e.dst)])
-                return vt & _probe_pair_member(sv, dv, t_s, t_o, paths)
-
-            def pair_via_gather(bt, vt):
-                gb, gv, shipped = gathered_bindings(bt, vt)
-                t_s, t_o = local_pair_tables()
-                nb, nv, over = _compress_rows(
-                    gb, pair_keep(gb, gv, t_s, t_o), capacity)
-                return nb, nv, over, shipped
-
-            def pair_via_gather_c(bt, vt):
-                c_ts, c_to = carry_prop_tables()
-                return pair_via_gather(bt, vt) + (c_ts, c_to)
-
-            def pair_via_edges(bt, vt):
-                t_s, t_o = gathered_prop_tables()
-                keep = pair_keep(bt, vt, t_s, t_o)
-                return (jnp.where(keep[:, None], bt, -1), keep,
-                        jnp.int32(0), jnp.int32(sc.edge_rows), t_s, t_o)
-
-            if mode == "skip":
-                t_s, t_o = local_pair_tables()
-                valid = pair_keep(bind, valid, t_s, t_o)
-                bind = jnp.where(valid[:, None], bind, -1)
-                over = jnp.int32(0)
-                dec_v, row_v = jnp.int32(COMM_SKIP), jnp.int32(0)
-            elif mode == "gather":
-                bind, valid, over, shipped = pair_via_gather(bind, valid)
-                dec_v, row_v = jnp.int32(COMM_GATHER), shipped
-            else:  # dynamic: ship the smaller side
-                (bind, valid, over, _), dec_v, row_v = ship_smaller_side(
-                    pair_via_gather_c, pair_via_edges)
-            ovf = jnp.maximum(ovf, over)
-        else:
-            # expansion: probe the known endpoint against the property's
-            # (key -> payload) table; keys are subjects when the source
-            # is bound, objects when the destination is.
-            known = e.src if s_known else e.dst
-
-            def probe_vals(bt):
-                nr = bt.shape[0]
-                return (jnp.full((nr,), known, jnp.int32) if known >= 0
-                        else bt[:, col_idx(known)])
-
-            def local_table():
-                # the property's sorted (key -> payload) table: a CSR
-                # window slice when packed tables are available (keys
-                # already sorted, no trace-time argsort), the masked
-                # argsort build otherwise
+            if step == 0:
+                # initialize from the property's local edge list.  With CSR
+                # tables the candidate rows are the property's packed run (a
+                # static window, identically (s, o)-ordered on every device
+                # -- the same order the (p, s, o)-sorted fallback scan
+                # yields, so seed decimation stripes identically); without
+                # them, scan the full padded columns.
                 if csr is not None:
-                    keys, payload, _n = csr_window(e.prop, s_known)
-                    return keys, payload
-                if s_known:
-                    return _edge_table_for_prop(s, p, o, e.prop)
-                sel_ = p == e.prop
-                okeys = jnp.where(sel_, o, imax)
-                oorder = jnp.argsort(okeys)
-                return okeys[oorder], s[oorder]
+                    seed_s, seed_o, n_live = csr_window(e.prop, True)
+                    live = jnp.arange(seed_s.shape[0], dtype=jnp.int32) \
+                        < n_live
+                else:
+                    seed_s, seed_o, live = s, o, (p == e.prop)
+                sel = live
+                if e.src >= 0:
+                    sel &= seed_s == e.src
+                if e.dst >= 0:
+                    sel &= seed_o == e.dst
+                if e.src < 0 and e.src == e.dst:
+                    sel &= seed_s == seed_o
+                if route_ranks is not None and axis is not None:
+                    # routed execution: devices outside the route never
+                    # seed (rank -1), so they hold zero valid rows for the
+                    # whole query; with decimation the members additionally
+                    # stripe the (route-complete, identically-ordered) seed
+                    # list among themselves in rendezvous-rank order
+                    my_rank = jnp.asarray(
+                        list(route_ranks),
+                        jnp.int32)[jax.lax.axis_index(axis)]
+                    if seed_decimate:
+                        rank = jnp.cumsum(sel) - 1
+                        sel &= (rank % max(route_width, 1)) == my_rank
+                    else:
+                        sel &= my_rank >= 0
+                elif seed_decimate and axis is not None:
+                    # step 0's property is shard-complete: every device sees
+                    # the identical, identically-ordered seed list, so each
+                    # keeping every m-th row partitions the seeds exactly
+                    # (balanced work, no cross-device duplicates, no m-fold
+                    # blowup of downstream binding counts)
+                    rank = jnp.cumsum(sel) - 1
+                    sel &= (rank % axis_size) == jax.lax.axis_index(axis)
+                (s_col, o_col), valid = compact_rows(sel, (seed_s, seed_o),
+                                                     capacity, fill=-1)
+                ovf = jnp.maximum(
+                    ovf, sel.sum().astype(jnp.int32) - capacity)
+                cols = []
+                if e.src < 0:
+                    var_cols.append(e.src)
+                    cols.append(s_col)
+                if e.dst < 0 and e.dst != e.src:
+                    var_cols.append(e.dst)
+                    cols.append(o_col)
+                bind = (jnp.stack(cols, axis=1) if cols
+                        else jnp.zeros((capacity, 0), jnp.int32)
+                        ).astype(jnp.int32)
+                continue
 
-            def exp_via_gather(bt, vt):
-                # the fused Pallas kernel runs dedup -> expand -> filter
-                # in one SMEM pass over the raw gathered table; the
-                # composition below (exact-dedup then _expand_fixed) is
-                # the default path and the semantics of record
+            sc = comm[step - 1] if comm is not None else None
+            mode = ("skip" if axis is None
+                    else sc.mode if sc is not None else "gather")
+            n_in = len(var_cols)          # binding columns entering the step
+
+            # cross-step cache state for this step's property ("dynamic"
+            # steps only: "skip" never gathers, "gather" never ships edges)
+            cache = edge_cache.get(e.prop) if mode == "dynamic" else None
+            have0 = cache[2] if cache is not None else jnp.bool_(False)
+
+            # -- shared builders for this step (all shapes static) ----------
+            def local_pair_tables():
+                if csr is not None:
+                    t_s, t_o, _n = csr_window(e.prop, True, pay_fill=imax)
+                    return t_s, t_o
+                sel_ = p == e.prop
+                return jnp.where(sel_, s, imax), jnp.where(sel_, o, imax)
+
+            def fresh_prop_tables():
+                # the edge-shipping side: this device's OWNED rows of the
+                # property, compacted into the static ship buffer
+                # (sc.gather_cap == SiteStore.prop_ship_window) and
+                # gathered from every device.  Ownership (exactly one
+                # device per resident edge, see SiteStore) makes the
+                # gathered table each resident edge exactly once: valid
+                # rows on the wire, not the padded window, and no
+                # replicated duplicates to re-expand.  Compacting a
+                # subsequence of the (s, o)-sorted run keeps it sorted;
+                # the imax fill sorts last, as before.
+                if csr is not None:
+                    fk, fp, n_run = csr_window(e.prop, True, pay_fill=imax)
+                    ow = owned_run_window(e.prop, fk.shape[0], n_run)
+                    (ls, lo_), _ = compact_rows(ow, (fk, fp), sc.gather_cap)
+                else:
+                    (ls, lo_), _ = compact_rows(p == e.prop, (s, o),
+                                                sc.gather_cap)
+                return (jax.lax.all_gather(ls, axis, tiled=True),
+                        jax.lax.all_gather(lo_, axis, tiled=True))
+
+            def gathered_prop_tables():
+                # reuse an earlier step's gather of the same property when
+                # this trace already holds one; gather fresh otherwise
+                if cache is None:
+                    return fresh_prop_tables()
+                return jax.lax.cond(have0, lambda: (cache[0], cache[1]),
+                                    fresh_prop_tables)
+
+            def carry_prop_tables():
+                # equal-shape stand-ins the binding-gather branch returns so
+                # both lax.cond branches agree; an incumbent cache entry is
+                # carried through unchanged (stand-ins are only ever stored
+                # with have=False and never read back as tables)
+                if cache is not None:
+                    return cache[0], cache[1]
+                rows_ = axis_size * sc.gather_cap
+                return (jnp.full((rows_,), imax, jnp.int32),
+                        jnp.full((rows_,), imax, jnp.int32))
+
+            def gathered_bindings(bt, vt):
                 gb = jax.lax.all_gather(bt, axis, tiled=True)
                 gv = jax.lax.all_gather(vt, axis, tiled=True)
-                shipped = gv.sum().astype(jnp.int32)
-                keys, payload = local_table()
-                if _use_pallas_probes("fused_join"):
-                    _note(paths, "fused_join_kernel")
-                    nb, nc, nv, over = fused_join(
-                        gb, gv, probe_vals(gb), keys, payload, capacity)
-                else:
-                    _note(paths, "jnp_join")
-                    gb, gv = _dedup_padded(gb, gv, paths)
-                    nb, nc, nv, over = _expand_fixed(
-                        gb, gv, probe_vals(gb), keys, payload, capacity,
-                        paths)
-                return nb, nc, nv, over, shipped
+                shipped = gv.sum().astype(jnp.int32)   # rows on the wire
+                gb, gv = _dedup_padded(gb, gv, paths)
+                return gb, gv, shipped
 
-            def exp_via_gather_c(bt, vt):
-                c_ts, c_to = carry_prop_tables()
-                return exp_via_gather(bt, vt) + (c_ts, c_to)
+            def ship_smaller_side(via_gather, via_edges):
+                # dynamic decision: psum the live global binding count and
+                # run the cheaper branch.  Cost comparison in float32:
+                # n_glob * row_bytes can exceed int32 on big meshes, and
+                # edge_bytes can exceed int32 as a trace-time constant;
+                # mantissa rounding is harmless for a heuristic.  The byte
+                # formulas are the ledger's (bind_row_bytes / edge_bytes),
+                # so decision and accounting cannot diverge.  Both branches
+                # return the (possibly stand-in) global edge tables last, so
+                # the cross-step cache survives the cond; a cached table
+                # makes the edge side free (COMM_EDGE_CACHED, zero bytes),
+                # which the predicate accounts for.
+                n_glob = jax.lax.psum(valid.sum().astype(jnp.int32), axis)
+                gather_cost = n_glob.astype(jnp.float32) \
+                    * float(bind_row_bytes(n_in))
+                edge_cost = jnp.where(have0, jnp.float32(0.0),
+                                      jnp.float32(sc.edge_bytes))
+                pred = gather_cost <= edge_cost
+                out = jax.lax.cond(pred, via_gather, via_edges, bind, valid)
+                *res, c_ts, c_to = out
+                edge_cache[e.prop] = (c_ts, c_to, have0 | ~pred)
+                dec = jnp.where(
+                    pred, COMM_GATHER,
+                    jnp.where(have0, COMM_EDGE_CACHED, COMM_EDGE)
+                ).astype(jnp.int32)
+                return tuple(res), dec, n_glob
 
-            def exp_via_edges(bt, vt):
-                g_s, g_o = gathered_prop_tables()
-                gk, gp = (g_s, g_o) if s_known else (g_o, g_s)
-                gorder = jnp.argsort(gk)
-                nb, nc, nv, over = _expand_fixed(
-                    bt, vt, probe_vals(bt), gk[gorder], gp[gorder],
-                    capacity, paths)
-                return nb, nc, nv, over, jnp.int32(sc.edge_rows), g_s, g_o
+            if s_known and d_known:
+                # cycle close: membership of the bound (src, dst) pair among
+                # the property's edges.  Sentinel table rows (INT32_MAX,
+                # INT32_MAX) never equal a real id pair; invalid probe rows
+                # are masked via ``vt``.
+                def pair_keep(bt, vt, t_s, t_o):
+                    nr = bt.shape[0]
+                    sv = (jnp.full((nr,), e.src, jnp.int32) if e.src >= 0
+                          else bt[:, col_idx(e.src)])
+                    dv = (jnp.full((nr,), e.dst, jnp.int32) if e.dst >= 0
+                          else bt[:, col_idx(e.dst)])
+                    return vt & _probe_pair_member(sv, dv, t_s, t_o, paths)
 
-            if mode == "skip":
-                keys, payload = local_table()
-                bind, new_col, valid, over = _expand_fixed(
-                    bind, valid, probe_vals(bind), keys, payload, capacity,
-                    paths)
-                dec_v, row_v = jnp.int32(COMM_SKIP), jnp.int32(0)
-            elif mode == "gather":
-                bind, new_col, valid, over, shipped = exp_via_gather(
-                    bind, valid)
-                dec_v, row_v = jnp.int32(COMM_GATHER), shipped
-            else:  # dynamic: ship the smaller side
-                (bind, new_col, valid, over, _), dec_v, row_v = \
-                    ship_smaller_side(exp_via_gather_c, exp_via_edges)
-            ovf = jnp.maximum(ovf, over)
-            new_var = e.dst if s_known else e.src
-            if new_var < 0:
-                var_cols.append(new_var)
-                bind = jnp.concatenate([bind, new_col[:, None]], axis=1)
+                def pair_via_gather(bt, vt):
+                    gb, gv, shipped = gathered_bindings(bt, vt)
+                    t_s, t_o = local_pair_tables()
+                    nb, nv, over = _compress_rows(
+                        gb, pair_keep(gb, gv, t_s, t_o), capacity)
+                    return nb, nv, over, shipped
+
+                def pair_via_gather_c(bt, vt):
+                    c_ts, c_to = carry_prop_tables()
+                    return pair_via_gather(bt, vt) + (c_ts, c_to)
+
+                def pair_via_edges(bt, vt):
+                    t_s, t_o = gathered_prop_tables()
+                    keep = pair_keep(bt, vt, t_s, t_o)
+                    return (jnp.where(keep[:, None], bt, -1), keep,
+                            jnp.int32(0), jnp.int32(sc.edge_rows), t_s, t_o)
+
+                if mode == "skip":
+                    t_s, t_o = local_pair_tables()
+                    valid = pair_keep(bind, valid, t_s, t_o)
+                    bind = jnp.where(valid[:, None], bind, -1)
+                    over = jnp.int32(0)
+                    dec_v, row_v = jnp.int32(COMM_SKIP), jnp.int32(0)
+                elif mode == "gather":
+                    bind, valid, over, shipped = pair_via_gather(bind, valid)
+                    dec_v, row_v = jnp.int32(COMM_GATHER), shipped
+                else:  # dynamic: ship the smaller side
+                    (bind, valid, over, _), dec_v, row_v = ship_smaller_side(
+                        pair_via_gather_c, pair_via_edges)
+                ovf = jnp.maximum(ovf, over)
             else:
-                valid = valid & (new_col == new_var)
-                bind = jnp.where(valid[:, None], bind, -1)
+                # expansion: probe the known endpoint against the property's
+                # (key -> payload) table; keys are subjects when the source
+                # is bound, objects when the destination is.
+                known = e.src if s_known else e.dst
 
-        decs.append(dec_v)
-        rows.append(row_v)
+                def probe_vals(bt):
+                    nr = bt.shape[0]
+                    return (jnp.full((nr,), known, jnp.int32) if known >= 0
+                            else bt[:, col_idx(known)])
+
+                def local_table():
+                    # the property's sorted (key -> payload) table: a CSR
+                    # window slice when packed tables are available (keys
+                    # already sorted, no trace-time argsort), the masked
+                    # argsort build otherwise
+                    if csr is not None:
+                        keys, payload, _n = csr_window(e.prop, s_known)
+                        return keys, payload
+                    if s_known:
+                        return _edge_table_for_prop(s, p, o, e.prop)
+                    sel_ = p == e.prop
+                    okeys = jnp.where(sel_, o, imax)
+                    oorder = jnp.argsort(okeys)
+                    return okeys[oorder], s[oorder]
+
+                def exp_via_gather(bt, vt):
+                    # the fused Pallas kernel runs dedup -> expand -> filter
+                    # in one SMEM pass over the raw gathered table; the
+                    # composition below (exact-dedup then _expand_fixed) is
+                    # the default path and the semantics of record
+                    gb = jax.lax.all_gather(bt, axis, tiled=True)
+                    gv = jax.lax.all_gather(vt, axis, tiled=True)
+                    shipped = gv.sum().astype(jnp.int32)
+                    keys, payload = local_table()
+                    if _use_pallas_probes("fused_join"):
+                        _note(paths, "fused_join_kernel")
+                        nb, nc, nv, over = fused_join(
+                            gb, gv, probe_vals(gb), keys, payload, capacity)
+                    else:
+                        _note(paths, "jnp_join")
+                        gb, gv = _dedup_padded(gb, gv, paths)
+                        nb, nc, nv, over = _expand_fixed(
+                            gb, gv, probe_vals(gb), keys, payload, capacity,
+                            paths)
+                    return nb, nc, nv, over, shipped
+
+                def exp_via_gather_c(bt, vt):
+                    c_ts, c_to = carry_prop_tables()
+                    return exp_via_gather(bt, vt) + (c_ts, c_to)
+
+                def exp_via_edges(bt, vt):
+                    g_s, g_o = gathered_prop_tables()
+                    gk, gp = (g_s, g_o) if s_known else (g_o, g_s)
+                    gorder = jnp.argsort(gk)
+                    nb, nc, nv, over = _expand_fixed(
+                        bt, vt, probe_vals(bt), gk[gorder], gp[gorder],
+                        capacity, paths)
+                    return nb, nc, nv, over, jnp.int32(sc.edge_rows), g_s, g_o
+
+                if mode == "skip":
+                    keys, payload = local_table()
+                    bind, new_col, valid, over = _expand_fixed(
+                        bind, valid, probe_vals(bind), keys, payload, capacity,
+                        paths)
+                    dec_v, row_v = jnp.int32(COMM_SKIP), jnp.int32(0)
+                elif mode == "gather":
+                    bind, new_col, valid, over, shipped = exp_via_gather(
+                        bind, valid)
+                    dec_v, row_v = jnp.int32(COMM_GATHER), shipped
+                else:  # dynamic: ship the smaller side
+                    (bind, new_col, valid, over, _), dec_v, row_v = \
+                        ship_smaller_side(exp_via_gather_c, exp_via_edges)
+                ovf = jnp.maximum(ovf, over)
+                new_var = e.dst if s_known else e.src
+                if new_var < 0:
+                    var_cols.append(new_var)
+                    bind = jnp.concatenate([bind, new_col[:, None]], axis=1)
+                else:
+                    valid = valid & (new_col == new_var)
+                    bind = jnp.where(valid[:, None], bind, -1)
+
+            decs.append(dec_v)
+            rows.append(row_v)
 
     dec_arr = (jnp.stack(decs) if decs else jnp.zeros((0,), jnp.int32))
     row_arr = (jnp.stack(rows) if rows else jnp.zeros((0,), jnp.int32))
@@ -1144,9 +1149,10 @@ def make_spmd_matcher(mesh: Mesh, axis: str, pattern: QueryGraph,
             axis_size=m, seed_decimate=seed_decimate, csr=csr,
             prop_windows=prop_windows, route_ranks=route_ranks,
             route_width=route_width, paths=join_paths)
-        g_bind = jax.lax.all_gather(bind, axis, tiled=True)
-        g_valid = jax.lax.all_gather(valid, axis, tiled=True)
-        g_ovf = jax.lax.all_gather(ovf[None], axis, tiled=True)
+        with jax.named_scope("final_gather"):
+            g_bind = jax.lax.all_gather(bind, axis, tiled=True)
+            g_valid = jax.lax.all_gather(valid, axis, tiled=True)
+            g_ovf = jax.lax.all_gather(ovf[None], axis, tiled=True)
         return g_bind, g_valid, g_ovf, dec, rows
 
     fn = jax.shard_map(per_site, mesh=mesh,
@@ -1255,10 +1261,17 @@ class SpmdEngine(EngineBase):
     tier -- plus a ``final_gather`` record; the records are built from
     the same per-step decision/rows vectors the ledger reads, so their
     byte sum reconciles *exactly* with ``stats().comm_bytes`` and their
-    per-decision counts with the step counters.  Tracing happens on the
-    host after device results are fetched: nothing new is traced inside
-    ``shard_map``, and a disabled tracer skips record building
-    entirely.
+    per-decision counts with the step counters.  Under the root span,
+    each capacity attempt opens a ``match`` span (the matcher call
+    through ``block_until_ready``: the host waiting on the device;
+    attrs ``capacity``, ``compiled``, ``overflow``) and a ``fetch``
+    span (the copy to the host; ``bytes``), then the host work opens
+    ``dedup`` (``rows_in``, ``rows_out``, ``reused``) and ``filter``
+    (``rows``).  A member of a shape-shared batch group that reuses
+    the group's device run has no ``match``/``fetch`` of its own.
+    Tracing happens on the host: nothing new is traced inside
+    ``shard_map``, and a disabled tracer opens no span and skips record
+    building entirely.
     """
 
     trace_name = "spmd"
@@ -1459,15 +1472,26 @@ class SpmdEngine(EngineBase):
         cap = self._cap_hints.get(norm.edges, self._start_capacity(norm))
         caps: List[int] = []
         attempts: List[Tuple[np.ndarray, np.ndarray, int]] = []
+        tr = self.tracer
+        trace_on = tr.enabled
         while True:
             caps.append(cap)
+            n_compiled = self._compiles
             fn = self._matcher(norm, cap)
             use_csr = self.store.csr_arrays() is not None
-            bind, valid, ovf, dec, rows = jax.device_get(
-                fn(*_matcher_args(self.store, use_csr)))
+            with (tr.span("match", capacity=cap,
+                          compiled=self._compiles > n_compiled)
+                  if trace_on else NULL_SPAN) as match_sp:
+                out = fn(*_matcher_args(self.store, use_csr))
+                jax.block_until_ready(out)
+            with (tr.span("fetch", bytes=sum(x.nbytes for x in out))
+                  if trace_on else NULL_SPAN):
+                bind, valid, ovf, dec, rows = jax.device_get(out)
             attempts.append((np.asarray(dec), np.asarray(rows),
                              int(np.asarray(valid).sum())))
-            if int(np.max(np.asarray(ovf), initial=0)) <= 0:
+            overflow = int(np.max(np.asarray(ovf), initial=0)) > 0
+            match_sp.set("overflow", overflow)
+            if not overflow:
                 self._cap_hints[norm.edges] = cap
                 return np.asarray(bind), np.asarray(valid), caps, attempts
             self._bump("overflow_events")
@@ -1508,21 +1532,29 @@ class SpmdEngine(EngineBase):
             bind, valid, caps, attempts = self._run_exact(norm)
             if self._shared_run_key == norm.edges:
                 self._shared_run = (bind, valid, caps, attempts)
-        rows = bind[valid]
-        if rows.size:
-            rows = np.unique(rows, axis=0)
-        # re-apply the constants the normalization stripped
-        nmap = query.normalization_map()
-        var_order, step_in_cols = _var_col_trace(norm)
-        col_of = {nv: i for i, nv in enumerate(var_order)}
-        keep = np.ones(rows.shape[0], dtype=bool)
-        for orig, nv in nmap.items():
-            if orig >= 0:
-                keep &= rows[:, col_of[nv]] == orig
-        rows = rows[keep]
-        bindings = {orig: rows[:, col_of[nv]].astype(np.int32)
-                    for orig, nv in nmap.items() if orig < 0}
-        n = int(rows.shape[0])
+        tr = self.tracer
+        trace_on = tr.enabled
+        with (tr.span("dedup", reused=reused) if trace_on
+              else NULL_SPAN) as sp:
+            rows = bind[valid]
+            sp.set("rows_in", int(rows.shape[0]))
+            if rows.size:
+                rows = np.unique(rows, axis=0)
+            sp.set("rows_out", int(rows.shape[0]))
+        with (tr.span("filter") if trace_on else NULL_SPAN) as sp:
+            # re-apply the constants the normalization stripped
+            nmap = query.normalization_map()
+            var_order, step_in_cols = _var_col_trace(norm)
+            col_of = {nv: i for i, nv in enumerate(var_order)}
+            keep = np.ones(rows.shape[0], dtype=bool)
+            for orig, nv in nmap.items():
+                if orig >= 0:
+                    keep &= rows[:, col_of[nv]] == orig
+            rows = rows[keep]
+            bindings = {orig: rows[:, col_of[nv]].astype(np.int32)
+                        for orig, nv in nmap.items() if orig < 0}
+            n = int(rows.shape[0])
+            sp.set("rows", n)
         # communication ledger, from the per-step decisions the matcher
         # reported: logical data-plane bytes on the wire per step (each
         # device ships to the other m-1 peers), either the valid
@@ -1544,8 +1576,6 @@ class SpmdEngine(EngineBase):
         # With routing off (or a whole-mesh route) this is the old m-1.
         w = route.width if route is not None else m
         routed = route is not None and route.width < m
-        tr = self.tracer
-        trace_on = tr.enabled
         comm = 0
         if reused:
             # the device run -- and every collective in it -- happened

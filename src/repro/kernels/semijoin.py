@@ -109,11 +109,12 @@ def _table_spec(b: int) -> pl.BlockSpec:
         lambda i, j, fb, wd: (fb[i] + jnp.minimum(j, wd[i] - 1), 0, 0))
 
 
-def _blocked_call(kernel, queries, tables, first_blk, widths, nsteps,
+def _blocked_call(kernel, name, queries, tables, first_blk, widths, nsteps,
                   interpret):
-    """Shared pallas_call of the blocked kernels: ``queries`` are the
-    (nq_blocks, 1, BM) query-side arrays, ``tables`` the (nt_blocks, 1,
-    BN) table-side arrays; out is (nq_blocks, 1, BM) int32."""
+    """Shared pallas_call of the blocked kernels: ``name`` names the
+    kernel in HLO and profiler traces, ``queries`` are the (nq_blocks,
+    1, BM) query-side arrays, ``tables`` the (nt_blocks, 1, BN)
+    table-side arrays; out is (nq_blocks, 1, BM) int32."""
     nqb, _, bm = queries[0].shape
     bn = tables[0].shape[2]
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -128,6 +129,7 @@ def _blocked_call(kernel, queries, tables, first_blk, widths, nsteps,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((nqb, 1, bm), jnp.int32),
         interpret=interpret,
+        name=name,
     )(first_blk, widths, *queries, *tables)
 
 
@@ -143,8 +145,9 @@ def semijoin_blocks(queries_3d: jax.Array, table_3d: jax.Array,
                 first_blk + width <= nt_blocks).
     nsteps:     inner grid extent (max overlap width).
     """
-    kern = _count_kernel if count else _semijoin_kernel
-    return _blocked_call(kern, (queries_3d,), (table_3d,), first_blk,
+    kern, name = ((_count_kernel, "join_count") if count
+                  else (_semijoin_kernel, "semijoin"))
+    return _blocked_call(kern, name, (queries_3d,), (table_3d,), first_blk,
                          widths, nsteps, interpret)
 
 
@@ -157,8 +160,9 @@ def pair_semijoin_blocks(qs_3d: jax.Array, qo_3d: jax.Array,
     qs/qo: (nq_blocks, 1, BM) query pairs lexsorted by (s, o), INT32_MAX
     padded; ts/to: (nt_blocks, 1, BN) table pairs likewise.  first_blk /
     widths: subject-column block plan (see ``ops._block_plan_1d``)."""
-    return _blocked_call(_pair_kernel, (qs_3d, qo_3d), (ts_3d, to_3d),
-                         first_blk, widths, nsteps, interpret)
+    return _blocked_call(_pair_kernel, "pair_semijoin", (qs_3d, qo_3d),
+                         (ts_3d, to_3d), first_blk, widths, nsteps,
+                         interpret)
 
 
 # ----------------------------------------------------------------------
@@ -361,6 +365,7 @@ def dedup_blocks(bind_cm: jax.Array, valid_i32: jax.Array, C: int, V: int,
         out_specs=_smem_spec(),
         scratch_shapes=[pltpu.SMEM((H,), jnp.int32)],
         interpret=interpret,
+        name="dedup_rows",
     )(bind_cm, valid_i32)
 
 
@@ -387,4 +392,5 @@ def fused_join_blocks(bind_cm: jax.Array, valid_i32: jax.Array,
                         pltpu.SMEM((C,), jnp.int32),
                         pltpu.SMEM((C,), jnp.int32)],
         interpret=interpret,
+        name="fused_join",
     )(bind_cm, valid_i32, probe, keys, pay)

@@ -5,9 +5,10 @@ Three modules, one pipeline:
 
 * ``trace``   -- ``Tracer`` / ``Span`` / ring-buffered ``TraceStore``:
   one root span per executed query on every backend, per-site child
-  spans on the host engine, structured per-join-step communication
+  spans on the host engine, ``match`` / ``fetch`` / ``dedup`` /
+  ``filter`` child spans and structured per-join-step communication
   records on the SPMD engine (reconciling exactly with the byte
-  ledger).
+  ledger); every span mirrored into a running JAX profiler trace.
 * ``metrics`` -- ``MetricsRegistry`` of counters, gauges (with change
   timelines), and fixed-bucket latency histograms (p50/p90/p99 derived
   from bucket counts, merge-able across engines).  Fed by
@@ -17,8 +18,8 @@ Three modules, one pipeline:
   ``BENCH_*.json``), ``to_prom_text()`` Prometheus exposition, and
   ``dump_spans()`` / ``spans.jsonl``.
 
-See ``docs/observability.md`` for the span model, the metric name
-catalogue, and how to read ``bench_latency`` output.
+See ``docs/observability.md`` for the span model and the metric name
+catalogue.
 """
 from .export import (REQUIRED_METRICS, SNAPSHOT_SCHEMA, dump_spans,
                      histogram_summary, registry_from_snapshot, snapshot,

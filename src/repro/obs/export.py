@@ -67,6 +67,8 @@ REQUIRED_SERVE_METRICS = (
     "repro_serve_batches_total",
     "repro_serve_batch_fallbacks_total",
     "repro_serve_breaker_opens_total",
+    "repro_serve_dispatched_total",
+    "repro_serve_queue_wait_s_total",
     "repro_serve_queue_depth",
     "repro_serve_breaker_state",
     "repro_serve_latency_seconds",

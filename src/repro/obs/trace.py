@@ -20,16 +20,23 @@ Model
   (the adaptive backend's inner host engine nests its ``"query"`` span
   under the adaptive one).  The clock is injectable (any ``() ->
   float`` monotonic callable) so tests drive deterministic timings.
+  An enabled tracer *mirrors* every span it opens into the profiler's
+  trace: by default a ``jax.profiler.TraceAnnotation`` of the same
+  name, entered and exited with the span, so a JAX profile shows the
+  program's spans on its host plane, on the device operations' own
+  time base.  The mirror is injectable too (``mirror=None`` turns it
+  off).
 * ``TraceStore`` -- ring buffer of *finished root* spans.  The ring
   caps memory regardless of stream length (``capacity`` roots; older
   traces fall off); ``finished_total`` still counts everything.
 
 Cost discipline: a disabled tracer (``Tracer(enabled=False)``, the
 process default) returns a shared no-op span from ``span()`` and makes
-``add_record``/``annotate`` single-branch no-ops.  Nothing here ever
-touches jax -- tracing happens strictly on the host side of every
-engine, after device results have been fetched, so enabling or
-disabling it cannot change what is traced inside ``jit``/``shard_map``.
+``add_record``/``annotate`` single-branch no-ops, and opens no
+profiler annotation.  Tracing happens strictly on the host side of
+every engine: the mirror is a host annotation, never a traced value,
+so enabling or disabling it cannot change what is traced inside
+``jit``/``shard_map``.
 
 Typical use::
 
@@ -46,10 +53,19 @@ import dataclasses
 import json
 import time
 from collections import deque
-from typing import (Any, Callable, Deque, Dict, Iterator, List, Optional,
-                    Tuple)
+from typing import (Any, Callable, ContextManager, Deque, Dict, Iterator,
+                    List, Optional)
 
 Clock = Callable[[], float]
+#: span name -> context manager that marks the span in a profiler trace
+Mirror = Callable[[str], ContextManager[Any]]
+
+
+def profiler_annotation(name: str) -> ContextManager[Any]:
+    """The default mirror: a ``jax.profiler.TraceAnnotation`` named
+    after the span (a no-op unless a profiler session is running)."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
 
 
 @dataclasses.dataclass
@@ -164,19 +180,27 @@ class TraceStore:
 
 class _SpanCtx:
     """Context manager binding one live ``Span`` to its tracer's
-    stack."""
-    __slots__ = ("_tracer", "_span")
+    stack, and its mirror annotation to the profiler's trace."""
+    __slots__ = ("_tracer", "_span", "_mirror")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self._span = span
+        self._mirror: Optional[ContextManager[Any]] = None
 
     def __enter__(self) -> Span:
+        if self._tracer.mirror is not None:
+            self._mirror = self._tracer.mirror(self._span.name)
+            self._mirror.__enter__()
         self._tracer._push(self._span)
         return self._span
 
     def __exit__(self, *exc) -> None:
-        self._tracer._pop(self._span)
+        try:
+            self._tracer._pop(self._span)
+        finally:
+            if self._mirror is not None:
+                self._mirror.__exit__(*exc)
 
 
 class Tracer:
@@ -188,15 +212,19 @@ class Tracer:
         clock: monotonic ``() -> float`` (seconds); defaults to
             ``time.perf_counter``.  Injectable for deterministic tests.
         capacity: ring size of the backing ``TraceStore``.
+        mirror: ``name -> context manager`` entered around every span
+            (``profiler_annotation`` by default); ``None`` for none.
 
     Not thread-safe: one tracer serves one query stream (the engines
     execute queries sequentially on the host).
     """
 
     def __init__(self, enabled: bool = True, clock: Optional[Clock] = None,
-                 capacity: int = 256):
+                 capacity: int = 256,
+                 mirror: Optional[Mirror] = profiler_annotation):
         self.enabled = bool(enabled)
         self.clock: Clock = clock or time.perf_counter
+        self.mirror = mirror
         self.store = TraceStore(capacity)
         self._stack: List[Span] = []
         self._next_span_id = 0

@@ -338,7 +338,8 @@ class FrontDoor:
         self._counters: Dict[str, Any] = {}
         for name in ("admitted", "completed", "failed",
                      "shed_queue_full", "shed_breaker", "deadline_expired",
-                     "batches", "batch_fallbacks", "breaker_opens"):
+                     "batches", "batch_fallbacks", "breaker_opens",
+                     "dispatched", "queue_wait_s"):
             self._counters[name] = self.metrics.counter(
                 f"repro_serve_{name}_total", backend="serve")
         self._g_depth = self.metrics.gauge("repro_serve_queue_depth",
@@ -509,8 +510,10 @@ class FrontDoor:
             for r in live:
                 wait = now - r.enqueued_at
                 self._h_wait.observe(wait)
+                self._counters["queue_wait_s"].inc(wait)
                 tracer.add_record({"kind": "admission",
                                    "queue_wait_s": wait})
+            self._counters["dispatched"].inc(len(live))
             n_probes = sum(1 for r in live if r.probe)
             try:
                 # one dispatch for the whole same-shape bucket: the

@@ -144,6 +144,91 @@ def test_trace_store_rejects_bad_capacity():
         TraceStore(0)
 
 
+class MirrorSpy:
+    """Fake profiler mirror: logs every annotation's enter and exit."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        spy = self
+
+        class _Annotation:
+            def __enter__(self):
+                spy.log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                spy.log.append(("exit", name, exc[0]))
+        return _Annotation()
+
+
+def test_mirror_wraps_every_span_and_unwinds_on_exception():
+    spy = MirrorSpy()
+    tr = Tracer(enabled=True, clock=FakeClock(), mirror=spy)
+    with pytest.raises(RuntimeError):
+        with tr.span("query"):
+            with tr.span("match"):
+                pass
+            with tr.span("dedup"):
+                raise RuntimeError("boom")
+    assert spy.log == [("enter", "query"), ("enter", "match"),
+                       ("exit", "match", None), ("enter", "dedup"),
+                       ("exit", "dedup", RuntimeError),
+                       ("exit", "query", RuntimeError)]
+    assert tr.current is None and tr.store.finished_total == 1
+
+
+def test_disabled_tracer_opens_no_mirror():
+    spy = MirrorSpy()
+    tr = Tracer(enabled=False, mirror=spy)
+    with tr.span("query"):
+        with tr.span("match"):
+            pass
+    assert spy.log == [] and len(tr.store) == 0
+
+
+def _profiled(tmp_path, tracers):
+    """Open ``query`` > (``match``, ``dedup``) on each tracer under a
+    CPU profiler session; the host-plane events by name, as
+    ``(start_ns, end_ns)``."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for tr in tracers:
+            with tr.span("query"):
+                with tr.span("match"):
+                    pass
+                with tr.span("dedup"):
+                    pass
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                        recursive=True)
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in ("query", "match", "dedup"):
+                        events.setdefault(ev.name, []).append(
+                            (ev.start_ns, ev.start_ns + ev.duration_ns))
+    return events
+
+
+def test_spans_appear_nested_in_a_profiler_trace(tmp_path):
+    ev = _profiled(tmp_path, [Tracer(enabled=True)])
+    (q,), (m,), (d,) = ev["query"], ev["match"], ev["dedup"]
+    assert q[0] <= m[0] <= m[1] <= d[0] <= d[1] <= q[1]
+
+
+def test_disabled_tracer_writes_nothing_to_a_profiler_trace(tmp_path):
+    assert _profiled(tmp_path, [Tracer(enabled=False)]) == {}
+
+
 # ----------------------------------------------------------------------
 # Histogram percentile math
 # ----------------------------------------------------------------------

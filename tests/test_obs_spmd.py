@@ -20,6 +20,8 @@ import pytest
 from repro.core import (PartitionConfig, Session, build_plan,
                         generate_watdiv, generate_workload,
                         make_shape_queries)
+from repro.core import spmd as S
+from repro.core.query import QueryGraph
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import Tracer
 
@@ -139,7 +141,9 @@ def test_routed_trace_carries_route_width_and_reconciles(spmd_setup):
 @pytest.mark.slow
 def test_spmd_trace_covers_retry_tiers(spmd_setup):
     """A query forced through the overflow retry ladder traces every
-    attempted tier, and the bytes of *all* tiers are ledgered."""
+    attempted tier -- one ``match`` and one ``fetch`` span each, every
+    tier's first call compiling, only the last not overflowing -- and
+    the bytes of *all* tiers are ledgered."""
     g, plan = spmd_setup
     tracer = Tracer(enabled=True, capacity=64)
     sess = Session(plan, backend="spmd", tracer=tracer,
@@ -150,6 +154,12 @@ def test_spmd_trace_covers_retry_tiers(spmd_setup):
     root = tracer.store.spans()[-1]
     tiers = root.attrs["capacity_tiers"]
     assert tiers == sorted(tiers)
+    matches = root.find("match")
+    assert [m.attrs["capacity"] for m in matches] == tiers
+    assert len(root.find("fetch")) == len(tiers)
+    assert all(m.attrs["compiled"] for m in matches)
+    assert [m.attrs["overflow"] for m in matches] == \
+        [True] * (len(tiers) - 1) + [False]
     recs = [r for r in root.records if r["kind"] == "comm_step"]
     assert sum(r["bytes"] for r in recs) == sess.stats().comm_bytes
     if sess.engine.store.num_sites > 1 and len(tiers) > 1:
@@ -187,3 +197,92 @@ def test_spmd_ledger_identical_traced_vs_untraced(spmd_setup):
     assert sp.comm_bytes == st.comm_bytes
     assert sp.extra["gather_steps"] == st.extra["gather_steps"]
     assert sp.extra["skipped_gathers"] == st.extra["skipped_gathers"]
+
+
+# ----------------------------------------------------------------------
+# Child spans of an SPMD query: match / fetch per capacity attempt, then
+# the host's dedup and filter
+# ----------------------------------------------------------------------
+
+def _children(root):
+    return [c.name for c in root.children]
+
+
+def test_spmd_query_span_tree(spmd_setup):
+    g, plan = spmd_setup
+    tracer = Tracer(enabled=True, capacity=64)
+    sess = Session(plan, backend="spmd", tracer=tracer,
+                   metrics_registry=MetricsRegistry())
+    q = _shape_queries(g, per_shape=1)[1]
+    res = sess.execute(q)
+    root = tracer.store.spans()[-1]
+    assert root.name == "query"
+    assert _children(root) == ["match", "fetch", "dedup", "filter"]
+    match, fetch, dedup, filt = root.children
+    assert all(c.parent_id == root.span_id and c.children == []
+               for c in root.children)
+    # nested in the query span and in time order
+    assert root.start <= match.start
+    assert match.end <= fetch.start and fetch.end <= dedup.start
+    assert dedup.end <= filt.start and filt.end <= root.end
+    assert match.attrs["capacity"] == root.attrs["capacity_tiers"][0]
+    assert match.attrs["compiled"] is True
+    assert match.attrs["overflow"] is False
+    assert fetch.attrs["bytes"] > 0
+    assert dedup.attrs["reused"] is False
+    assert dedup.attrs["rows_in"] >= dedup.attrs["rows_out"] \
+        >= filt.attrs["rows"] == res.num_rows
+    sess.execute(q)                    # the same program, now compiled
+    assert tracer.store.spans()[-1].children[0].attrs["compiled"] is False
+
+
+def test_spmd_shape_shared_batch_members_reuse_the_run(spmd_setup):
+    """A batch of one shape runs the device once: the later members
+    have no ``match``/``fetch`` and their ``dedup`` is marked reused."""
+    g, plan = spmd_setup
+    tracer = Tracer(enabled=True, capacity=64)
+    sess = Session(plan, backend="spmd", tracer=tracer,
+                   metrics_registry=MetricsRegistry())
+    s, p, o = (np.asarray(a) for a in (g.s, g.p, g.o))
+    prop = int(p[0])
+    objs = np.unique(o[p == prop])[:3]
+    batch = [QueryGraph.make([(-1, -2, prop), (-2, int(c), prop)])
+             for c in objs]
+    sess.execute_many(batch, batch_size=len(batch))
+    first, *rest = tracer.store.spans()[-len(batch):]
+    tiers = len(first.attrs["capacity_tiers"])
+    assert _children(first) == ["match", "fetch"] * tiers + ["dedup",
+                                                            "filter"]
+    assert first.children[-2].attrs["reused"] is False
+    assert rest and all(_children(r) == ["dedup", "filter"] for r in rest)
+    assert all(r.children[0].attrs["reused"] is True for r in rest)
+
+
+def test_spmd_disabled_tracer_opens_no_child_span(spmd_setup,
+                                                  monkeypatch):
+    g, plan = spmd_setup
+    tracer = Tracer(enabled=False)
+    opened = []
+    monkeypatch.setattr(tracer, "span",
+                        lambda name, **kw: opened.append(name))
+    sess = Session(plan, backend="spmd", tracer=tracer,
+                   metrics_registry=MetricsRegistry())
+    sess.execute_many(_shape_queries(g, per_shape=1))
+    assert opened == [] and len(tracer.store) == 0
+
+
+def test_matcher_ops_carry_step_and_final_gather_scopes(spmd_setup):
+    """The named scopes are HLO metadata: every join step's operations
+    carry ``step<j>``, the closing all_gathers ``final_gather``."""
+    g, plan = spmd_setup
+    sess = Session(plan, backend="spmd",
+                   metrics_registry=MetricsRegistry())
+    eng = sess.engine
+    q = _shape_queries(g, per_shape=1)[1].normalize()
+    fn = eng._matcher(q, 64)
+    use_csr = eng.store.csr_arrays() is not None
+    text = fn.lower(*S._matcher_args(eng.store, use_csr)).as_text(
+        debug_info=True)
+    for j in range(q.num_edges):
+        assert f"step{j}/" in text
+    assert "final_gather/" in text

@@ -486,6 +486,29 @@ def test_span_chain_admission_batch_execute():
     assert len(waits) == 2                       # one per admitted member
 
 
+def test_dispatch_and_queue_wait_counters_count_exactly():
+    door, eng, clk = make_door(max_batch=2, max_delay_ms=10.0)
+    door.submit(FakeQuery("a", 1))
+    clk.advance(0.25)
+    door.submit(FakeQuery("a", 2))               # fills the bucket
+    clk.advance(0.5)
+    door.submit(FakeQuery("b", 3))
+    clk.advance(1.0)                             # "b" is past its delay
+    assert door.pump() == 2
+    st = door.stats()
+    assert st["dispatched"] == 3
+    # waits on the door's clock: 1.75 and 1.5 ("a"), 1.0 ("b")
+    assert st["queue_wait_s"] == pytest.approx(4.25)
+    f = door.submit(FakeQuery("c", 4), deadline_s=0.5)
+    clk.advance(1.0)
+    door.pump()                                  # expired: not dispatched
+    assert f.outcome == "deadline"
+    assert door.stats()["dispatched"] == 3
+    prom = door.metrics.counter("repro_serve_queue_wait_s_total",
+                                backend="serve")
+    assert prom.value == pytest.approx(4.25)
+
+
 def test_queue_depth_gauge_tracks_lifecycle():
     door, eng, clk = make_door(max_batch=100)
     g = door.metrics.gauge("repro_serve_queue_depth", backend="serve")

@@ -9,6 +9,7 @@ lowering check.
 """
 import importlib.util
 import itertools
+import re
 from pathlib import Path
 
 import jax
@@ -63,11 +64,14 @@ def _abstract(args, sharding):
 @pytest.mark.parametrize("name", ["semijoin", "join_count", "pair_semijoin",
                                   "dedup_rows", "fused_join"])
 def test_join_kernel_compiles_for_v5e(v5e, compiled_kernels, name):
-    """Each join kernel at the shapes ``chip_smoke.py`` runs."""
+    """Each join kernel at the shapes ``chip_smoke.py`` runs, its
+    custom call named after the kernel (the name a profiler trace
+    shows)."""
     op, args = _smoke().kernel_cases(0)[name]
     one = jax.sharding.SingleDeviceSharding(v5e.devices[0])
     text = jax.jit(op).lower(*_abstract(args, one)).compile().as_text()
     assert "tpu_custom_call" in text
+    assert re.search(rf"%{name}(\.\d+)? = .* custom-call\(", text)
 
 
 def _store_and_args(num_sites, mesh):
