@@ -85,7 +85,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..constants import INT32_SENTINEL
+from ..constants import INT32_SENTINEL, MAX_VERTEX_ID
 from ..kernels import ref as kref
 from ..obs.trace import NULL_SPAN
 from .engine import EngineBase
@@ -1169,6 +1169,39 @@ def _matcher_args(store: SiteStore, use_csr: bool) -> Tuple[jax.Array, ...]:
     return args
 
 
+def _unique_rows(rows: np.ndarray) -> Tuple[np.ndarray, bool]:
+    """Exactly ``np.unique(rows, axis=0)`` -- the same rows, dtype and
+    lexicographic order -- and whether the packed path ran.
+
+    ``axis=0`` sorts each row as one ``void`` scalar, field by field,
+    which is slow.  When every value is a vertex id in
+    ``[0, MAX_VERTEX_ID]`` and the columns fit 63 bits, each row packs
+    into one int64 key, first column in the high bits (key order is row
+    order), and one integer ``np.unique`` does the work.  Any other
+    table (more columns, a value out of range, not integers) falls back
+    to ``np.unique(rows, axis=0)``.  An empty table is returned as is.
+    """
+    n_cols = rows.shape[1]
+    bits = MAX_VERTEX_ID.bit_length()
+    mask = (1 << bits) - 1
+    fits = (np.issubdtype(rows.dtype, np.integer)
+            and 0 < n_cols * bits <= 63)
+    if rows.size == 0:
+        return rows, fits
+    if fits and rows.min() >= 0 and rows.max() <= MAX_VERTEX_ID:
+        key = np.zeros(rows.shape[0], np.int64)
+        for j in range(n_cols):
+            key <<= bits
+            key |= rows[:, j].astype(np.int64, copy=False)
+        key = np.unique(key)
+        out = np.empty((key.shape[0], n_cols), rows.dtype)
+        for j in range(n_cols - 1, -1, -1):
+            out[:, j] = key & mask
+            key >>= bits
+        return out, True
+    return np.unique(rows, axis=0), False
+
+
 def spmd_match(store: SiteStore, mesh: Mesh, axis: str,
                pattern: QueryGraph, capacity: int = 4096
                ) -> Tuple[np.ndarray, List[int]]:
@@ -1181,9 +1214,7 @@ def spmd_match(store: SiteStore, mesh: Mesh, axis: str,
     bind, valid, _ovf, _dec, _rows = jax.device_get(
         fn(*_matcher_args(store, use_csr)))
     cols = pattern_var_order(pattern)
-    rows = bind[np.asarray(valid)]
-    if rows.size:
-        rows = np.unique(rows, axis=0)
+    rows, _ = _unique_rows(bind[np.asarray(valid)])
     return rows, cols
 
 
@@ -1345,6 +1376,8 @@ class SpmdEngine(EngineBase):
         self._shared_run = None
         self._shared_run_key: Optional[Tuple] = None
         self._bump("batch_shape_hits", 0)
+        self._bump("dedup_packed", 0)
+        self._bump("dedup_fallback", 0)
         self._bump("capacity_retries", 0)
         self._bump("overflow_events", 0)
         self._bump("gather_steps", 0)
@@ -1538,9 +1571,10 @@ class SpmdEngine(EngineBase):
               else NULL_SPAN) as sp:
             rows = bind[valid]
             sp.set("rows_in", int(rows.shape[0]))
-            if rows.size:
-                rows = np.unique(rows, axis=0)
+            rows, packed = _unique_rows(rows)
             sp.set("rows_out", int(rows.shape[0]))
+            sp.set("packed", packed)
+        self._bump("dedup_packed" if packed else "dedup_fallback")
         with (tr.span("filter") if trace_on else NULL_SPAN) as sp:
             # re-apply the constants the normalization stripped
             nmap = query.normalization_map()
