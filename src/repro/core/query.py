@@ -78,26 +78,33 @@ class QueryGraph:
         return len(seen) == len(vs)
 
     # ------------------------------------------------------------------
-    def normalization_map(self) -> Dict[int, int]:
+    def normalization_map(self, keep: FrozenSet[int] = frozenset()
+                          ) -> Dict[int, int]:
         """Original vertex id -> normalized variable id, in edge/endpoint
-        traversal order.  THE canonical traversal: ``normalize`` and
-        ``constant_bindings`` are defined in terms of it, and the SPMD
-        engine uses it to re-apply constants after matching a normalized
-        pattern -- one implementation, no lockstep copies."""
+        traversal order; a constant in ``keep`` maps to itself.  THE
+        canonical traversal: ``normalize`` and ``constant_bindings`` are
+        defined in terms of it, and the SPMD engine uses it to re-apply
+        constants after matching a normalized pattern -- one
+        implementation, no lockstep copies."""
         mapping: Dict[int, int] = {}
         nxt = -1
         for e in self.edges:
             for v in (e.src, e.dst):
                 if v not in mapping:
-                    mapping[v] = nxt
-                    nxt -= 1
+                    if v >= 0 and v in keep:
+                        mapping[v] = v
+                    else:
+                        mapping[v] = nxt
+                        nxt -= 1
         return mapping
 
-    def normalize(self) -> "QueryGraph":
+    def normalize(self, keep: FrozenSet[int] = frozenset()
+                  ) -> "QueryGraph":
         """§4: replace every constant subject/object with a fresh variable
-        (generalized representation).  Properties are kept -- they are the
-        labels the whole technique keys on.  FILTERs were never modeled."""
-        m = self.normalization_map()
+        (generalized representation), except the constants in ``keep``.
+        Properties are kept -- they are the labels the whole technique
+        keys on.  FILTERs were never modeled."""
+        m = self.normalization_map(keep)
         return QueryGraph(tuple(QueryEdge(m[e.src], m[e.dst], e.prop)
                                 for e in self.edges))
 
@@ -174,18 +181,27 @@ def min_dfs_code(g: QueryGraph) -> Tuple:
         adj.setdefault(e.src, []).append((idx, e.dst, 0))
         adj.setdefault(e.dst, []).append((idx, e.src, 1))
 
-    # Self-loops break gSpan's minimal-extension pruning: a loop is only
-    # consumable (as a backward edge) while its vertex is rightmost, so
-    # always following the minimal extension can dead-end before the loop
-    # is emitted.  With a loop present we branch on *every* extension --
-    # still exact (prefix-pruned against the incumbent), and the min over
-    # all traversals is the same canonical form.
-    has_loop = any(e.src == e.dst for e in edges)
-
+    # The search follows only the minimal extension (ties all branch).
+    # That can dead-end: a backward edge is only consumable from the
+    # rightmost vertex, and a forward edge from a shallower vertex of the
+    # rightmost path compares smaller, so the minimal step can move the
+    # rightmost vertex on and strand the backward edge (a self-loop, or a
+    # cycle with a pendant edge at its corners, as in LUBM Q2 and Q9).
+    # Such patterns are searched again branching on *every* extension.
+    #
+    # Canonical either way: each pass starts at every vertex and branches
+    # on every tie, so the set of complete codes it enumerates -- and
+    # hence their minimum, and whether the first pass finds one at all --
+    # depends on the pattern's structure alone, not on its edge order or
+    # vertex ids (incumbent pruning only drops prefixes that cannot beat
+    # the minimum).  A complete code lists every edge once with its
+    # endpoints' discovery indices, labels and direction, so it rebuilds
+    # the pattern up to isomorphism: equal codes <=> isomorphic patterns.
+    # Where the first pass succeeds the code is the one it always gave.
     best: List[Optional[Tuple]] = [None]
 
     def rec(code: List[Tuple], disc: Dict[int, int], used: FrozenSet[int],
-            rightmost_path: List[int]) -> None:
+            rightmost_path: List[int], branch_all: bool) -> None:
         if best[0] is not None and tuple(code) > best[0][: len(code)]:
             return
         if len(code) == n:
@@ -214,9 +230,10 @@ def min_dfs_code(g: QueryGraph) -> Tuple:
         if not ext:
             return
         tmin = min(t for t, _, _ in ext)
-        for t, eidx, newv in ext:
-            if t != tmin and not has_loop:
-                continue
+        # smallest first, so that an incumbent that prunes well comes early
+        for t, eidx, newv in sorted(ext, key=lambda x: x[0]):
+            if t != tmin and not branch_all:
+                break
             code.append(t)
             if newv is not None:
                 disc2 = dict(disc)
@@ -226,13 +243,19 @@ def min_dfs_code(g: QueryGraph) -> Tuple:
                 idx = next(i for i, u in enumerate(rightmost_path)
                            if disc[u] == src_disc)
                 rmp2 = rightmost_path[: idx + 1] + [newv]
-                rec(code, disc2, used | {eidx}, rmp2)
+                rec(code, disc2, used | {eidx}, rmp2, branch_all)
             else:
-                rec(code, disc, used | {eidx}, rightmost_path)
+                rec(code, disc, used | {eidx}, rightmost_path, branch_all)
             code.pop()
 
-    for start in set([e.src for e in edges] + [e.dst for e in edges]):
-        rec([], {start: 0}, frozenset(), [start])
+    starts = set([e.src for e in edges] + [e.dst for e in edges])
+    # a self-loop always took the exhaustive search: keep its codes
+    for branch_all in ((True,) if any(e.src == e.dst for e in edges)
+                       else (False, True)):
+        for start in starts:
+            rec([], {start: 0}, frozenset(), [start], branch_all)
+        if best[0] is not None:
+            break
     if best[0] is None:
         raise RuntimeError("canonical DFS-code search found no code "
                            "(disconnected or malformed pattern?)")

@@ -614,15 +614,21 @@ def _compress_rows(bind: jax.Array, keep: jax.Array, capacity: int
     return out, valid, over
 
 
-def _var_col_trace(pattern: QueryGraph) -> Tuple[List[int], List[int]]:
+def _var_col_trace(pattern: QueryGraph
+                   ) -> Tuple[List[int], List[int], int]:
     """Host-side replay of ``_match_shard``'s column bookkeeping, without
     tracing.  Returns (final binding-column order, #columns entering each
-    join step >= 1) -- the latter sizes the per-step broadcast-join
-    gathers for the comm ledger."""
+    join step >= 1, #join steps that close a cycle) -- the second sizes
+    the per-step broadcast-join gathers for the comm ledger.  A step
+    closes a cycle when both its endpoints are variables already bound,
+    so it probes pair membership (``pair_semijoin``) instead of
+    expanding; a bound pair with a constant end probes alike but closes
+    no cycle."""
     order = _connected_edge_order(pattern)
     edges = pattern.edges
     var_cols: List[int] = []
     step_in_cols: List[int] = []
+    cycles = 0
     for step, ei in enumerate(order):
         e = edges[ei]
         if step == 0:
@@ -635,6 +641,7 @@ def _var_col_trace(pattern: QueryGraph) -> Tuple[List[int], List[int]]:
         s_known = e.src >= 0 or e.src in var_cols
         d_known = e.dst >= 0 or e.dst in var_cols
         if s_known and d_known:
+            cycles += e.src < 0 and e.dst < 0
             continue
         if s_known:
             if e.dst < 0:
@@ -642,7 +649,13 @@ def _var_col_trace(pattern: QueryGraph) -> Tuple[List[int], List[int]]:
         else:
             if e.src < 0:
                 var_cols.append(e.src)
-    return var_cols, step_in_cols
+    return var_cols, step_in_cols, cycles
+
+
+def cycle_close_steps(pattern: QueryGraph) -> int:
+    """How many join steps of ``_match_shard`` close a cycle (see
+    ``_var_col_trace``)."""
+    return _var_col_trace(pattern)[2]
 
 
 def pattern_var_order(pattern: QueryGraph) -> List[int]:
@@ -1222,6 +1235,13 @@ def spmd_match(store: SiteStore, mesh: Mesh, axis: str,
 # SPMD execution engine (Engine protocol)
 # ----------------------------------------------------------------------
 
+#: a constant stays in the compiled pattern when its side of the
+#: property (subjects or objects) averages at least this many edges per
+#: distinct value: a class of ``rdf:type`` is such a hub, and stripping
+#: it would scan every typing of every class.  Few values reach it, so
+#: few programs are compiled for them.
+HUB_EDGES_PER_VALUE = 4096
+
 class SpmdEngine(EngineBase):
     """``Engine``-protocol front over the SPMD ``SiteStore`` path.
 
@@ -1238,13 +1258,17 @@ class SpmdEngine(EngineBase):
     filter, so the jit cache is keyed by query **shape** x **capacity
     tier** -- a workload of thousands of template-instantiated queries
     compiles once per template (per tier), and the cache persists across
-    ``execute``/``execute_many`` calls for the engine's lifetime.
+    ``execute``/``execute_many`` calls for the engine's lifetime.  A
+    constant on a hub side of its property (``HUB_EDGES_PER_VALUE``,
+    e.g. the class of an ``rdf:type`` edge) stays in the compiled
+    pattern and is matched on the device: the shape then includes it.
 
     ``capacity`` bounds the per-device binding table.  Overflow is
     counted in-trace; on overflow the query transparently re-executes
     with doubled capacity (at most log2(max_capacity/capacity)
-    recompiles, each cached) until exact.  If ``max_capacity`` is still
-    not enough, a ``RuntimeError`` is raised -- never a silently
+    recompiles, each cached; an overflow that shows the table needs
+    more skips the tiers below it) until exact.  If ``max_capacity`` is
+    still not enough, a ``RuntimeError`` is raised -- never a silently
     truncated answer.  ``stats().extra`` reports ``capacity_retries``
     (re-executions at a higher tier) and ``overflow_events`` (attempts
     that overflowed).
@@ -1295,7 +1319,9 @@ class SpmdEngine(EngineBase):
     per-decision counts with the step counters.  Under the root span,
     each capacity attempt opens a ``match`` span (the matcher call
     through ``block_until_ready``: the host waiting on the device;
-    attrs ``capacity``, ``compiled``, ``overflow``) and a ``fetch``
+    attrs ``capacity``, ``compiled``, ``overflow``, and the program's
+    ``cycles`` (join steps that close a cycle, ``cycle_close_steps``)
+    and ``edges`` (the pattern's edge count)) and a ``fetch``
     span (the copy to the host; ``bytes``), then the host work opens
     ``dedup`` (``rows_in``, ``rows_out``, ``reused``) and ``filter``
     (``rows``).  A member of a shape-shared batch group that reuses
@@ -1364,6 +1390,9 @@ class SpmdEngine(EngineBase):
         # repeat queries start the retry ladder there instead of
         # re-climbing (and re-executing) every lower tier
         self._cap_hints: Dict[Tuple, int] = {}
+        # (property, subject side?) -> whether a constant there is kept
+        # in the compiled pattern (HUB_EDGES_PER_VALUE)
+        self._hub_sides: Dict[Tuple[int, bool], bool] = {}
         self._compiles = 0
         # bumped by swap_store: matcher cache entries are keyed by store
         # generation (a matcher closes over comm specs / routes planned
@@ -1445,6 +1474,33 @@ class SpmdEngine(EngineBase):
             self._seed_decim[pattern.edges] = dec
         return dec
 
+    def _hub_side(self, prop: int, subject_side: bool) -> bool:
+        """Whether ``prop``'s subjects (or objects) average at least
+        ``HUB_EDGES_PER_VALUE`` edges per distinct value in the graph."""
+        key = (prop, subject_side)
+        hub = self._hub_sides.get(key)
+        if hub is None:
+            g = self.graph
+            vals = (g.s if subject_side else g.o)[g.p == prop]
+            hub = vals.size >= HUB_EDGES_PER_VALUE * max(
+                np.unique(vals).size, 1)
+            self._hub_sides[key] = hub
+        return hub
+
+    def _shape(self, query: QueryGraph
+               ) -> Tuple[QueryGraph, Dict[int, int]]:
+        """The pattern this engine compiles for ``query`` and its
+        normalization map: constants stripped to variables, except those
+        on a hub side of their property (``_hub_side``) while a variable
+        remains."""
+        keep = frozenset(
+            v for e in query.edges
+            for v, subject_side in ((e.src, True), (e.dst, False))
+            if v >= 0 and self._hub_side(e.prop, subject_side))
+        if not query.variables():
+            keep = frozenset()
+        return query.normalize(keep), query.normalization_map(keep)
+
     def _start_capacity(self, pattern: QueryGraph) -> int:
         """First capacity tier for a pattern with no retry-ladder hint.
         A decimated seed step over ``r`` route members concentrates
@@ -1513,7 +1569,9 @@ class SpmdEngine(EngineBase):
             fn = self._matcher(norm, cap)
             use_csr = self.store.csr_arrays() is not None
             with (tr.span("match", capacity=cap,
-                          compiled=self._compiles > n_compiled)
+                          compiled=self._compiles > n_compiled,
+                          cycles=cycle_close_steps(norm),
+                          edges=norm.num_edges)
                   if trace_on else NULL_SPAN) as match_sp:
                 out = fn(*_matcher_args(self.store, use_csr))
                 jax.block_until_ready(out)
@@ -1522,7 +1580,8 @@ class SpmdEngine(EngineBase):
                 bind, valid, ovf, dec, rows = jax.device_get(out)
             attempts.append((np.asarray(dec), np.asarray(rows),
                              int(np.asarray(valid).sum())))
-            overflow = int(np.max(np.asarray(ovf), initial=0)) > 0
+            over_rows = int(np.max(np.asarray(ovf), initial=0))
+            overflow = over_rows > 0
             match_sp.set("overflow", overflow)
             if not overflow:
                 self._cap_hints[norm.edges] = cap
@@ -1536,7 +1595,14 @@ class SpmdEngine(EngineBase):
                     f"truncated answer.  Raise Session(spmd_capacity=...)"
                     f"/spmd_max_capacity (or SpmdEngine capacity/"
                     f"max_capacity) for this workload.")
-            cap = min(cap * 2, self.max_capacity)
+            # the overflow count is a floor of the rows the table must
+            # hold (steps after the first overflowing one saw a cut
+            # table): go straight to the first doubling that holds it
+            need = cap + over_rows
+            cap *= 2
+            while cap < need and cap < self.max_capacity:
+                cap *= 2
+            cap = min(cap, self.max_capacity)
             self._bump("capacity_retries")
 
     def _execute(self, query: QueryGraph) -> QueryResult:
@@ -1550,7 +1616,7 @@ class SpmdEngine(EngineBase):
                 "SPMD matcher requires constant properties (wildcard "
                 "property labels would match the -1 padding)")
         t0 = time.perf_counter()
-        norm = query.normalize()
+        norm, nmap = self._shape(query)
         # batch-level shape sharing: inside an _execute_batch group the
         # matcher output is identical for every member (same normalized
         # pattern, same store), so run the device program once and let
@@ -1577,12 +1643,11 @@ class SpmdEngine(EngineBase):
         self._bump("dedup_packed" if packed else "dedup_fallback")
         with (tr.span("filter") if trace_on else NULL_SPAN) as sp:
             # re-apply the constants the normalization stripped
-            nmap = query.normalization_map()
-            var_order, step_in_cols = _var_col_trace(norm)
+            var_order, step_in_cols, _ = _var_col_trace(norm)
             col_of = {nv: i for i, nv in enumerate(var_order)}
             keep = np.ones(rows.shape[0], dtype=bool)
             for orig, nv in nmap.items():
-                if orig >= 0:
+                if orig >= 0 and nv < 0:
                     keep &= rows[:, col_of[nv]] == orig
             rows = rows[keep]
             bindings = {orig: rows[:, col_of[nv]].astype(np.int32)
@@ -1710,7 +1775,7 @@ class SpmdEngine(EngineBase):
         """Group intra-batch queries by normalized shape key before
         dispatch.
 
-        Queries sharing ``query.normalize().edges`` hit the same jit
+        Queries sharing a compiled shape (``_shape``) hit the same jit
         cache entry AND -- because normalization strips the constants
         that differ between them -- produce the *identical* matcher
         output over this engine's store.  The sequential default would
@@ -1728,7 +1793,7 @@ class SpmdEngine(EngineBase):
                 # the error surfaces for exactly this query
                 groups.setdefault(("__prop_var__", i), []).append(i)
             else:
-                groups.setdefault(q.normalize().edges, []).append(i)
+                groups.setdefault(self._shape(q)[0].edges, []).append(i)
         out: List[Optional[QueryResult]] = [None] * len(batch)
         for key, idxs in groups.items():
             # key[:1] is safe on the empty tuple (zero-edge queries
@@ -1792,6 +1857,7 @@ class SpmdEngine(EngineBase):
         self._comm_specs.clear()
         self._seed_decim.clear()
         self._cap_hints.clear()
+        self._hub_sides.clear()
         self._shared_run = None
         self._shared_run_key = None
         self._store_gen += 1

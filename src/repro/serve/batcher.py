@@ -34,8 +34,9 @@ ShapeKey = Tuple  # tuple of normalized QueryEdge, hashable
 
 def shape_key(query) -> ShapeKey:
     """The micro-batching bucket key for ``query``: its normalized edge
-    structure -- the same key the SPMD engine's shape-keyed jit cache
-    uses, so one bucket == one compiled matcher entry."""
+    structure -- the SPMD engine's shape-keyed jit cache key, up to the
+    hub constants the engine keeps (``SpmdEngine._shape``), so one bucket
+    is one compiled matcher entry per kept constant."""
     return query.normalize().edges
 
 

@@ -224,6 +224,22 @@ def test_overflow_retry_count_is_logarithmic(rgraph, tiny_cap_plan):
     assert st2.extra["capacity_retries"] == st.extra["capacity_retries"]
 
 
+def test_overflow_retry_skips_tiers_the_overflow_rules_out(rgraph,
+                                                          tiny_cap_plan):
+    """A one-edge pattern overflows at its seed step, whose overflow
+    count is exact: one retry, straight to the first tier that holds the
+    device's rows, where doubling alone would climb through 4 and 8."""
+    q = QueryGraph.make([(-1, -2, 0)])
+    want = match_pattern(rgraph, q).num_rows
+    assert want > 4 * 8         # some device holds 16 rows or more
+    sess = Session(tiny_cap_plan, backend="spmd", spmd_capacity=2,
+                   spmd_max_capacity=1 << 14)
+    assert sess.execute(q).num_rows == want
+    st = sess.stats()
+    assert st.extra["capacity_retries"] == 1
+    assert st.extra["compiled_shapes"] == 2
+
+
 def test_overflow_at_retry_cap_raises_instead_of_truncating(rgraph,
                                                             tiny_cap_plan):
     q = QueryGraph.make([(-1, -2, 0)])
