@@ -482,14 +482,16 @@ def _use_pallas_probes(kernel: str) -> bool:
 #:   traced through the hash kernel / the jnp lexsort;
 #: * ``fused_join_kernel`` / ``jnp_join`` -- binding-gather expansion
 #:   steps traced through the fused kernel / the dedup + ``_expand_fixed``
-#:   composition.
+#:   composition;
+#: * ``expand_direct`` -- ``_expand_fixed`` calls, each mapping rows and
+#:   output slots by scatter and running max (no binary search).
 #:
 #: Both branches of a planner ``lax.cond`` are traced, so a dynamic step
 #: counts the paths of both.
 JOIN_PATHS = ("join_count_kernel", "join_count_jnp",
               "pair_semijoin_kernel", "pair_semijoin_jnp",
               "hash_dedup_kernel", "lexsort_dedup",
-              "fused_join_kernel", "jnp_join")
+              "fused_join_kernel", "jnp_join", "expand_direct")
 
 
 def _note(paths: Optional[Dict[str, int]], name: str) -> None:
@@ -528,6 +530,50 @@ def _probe_pair_member(q_s: jax.Array, q_o: jax.Array,
     return kref.pair_semijoin_ref(q_s, q_o, t_s, t_o)
 
 
+def _slot_rows(start: jax.Array, cnt: jax.Array, capacity: int
+               ) -> jax.Array:
+    """Output slot -> source row of an expansion step: for each slot ``t``
+    below the total, the row whose run of ``cnt`` slots from offset
+    ``start`` holds ``t``, which is ``searchsorted(start, t,
+    side="right") - 1``.  Built in one pass, not a binary search: each
+    row with slots in range writes its number at its offset, and a
+    running maximum carries it over the row's slots.  ``start`` (the
+    exclusive cumsum of ``cnt``) rises strictly over those rows until
+    the int32 cumsum wraps, and the first wrap reads negative; rows from
+    it on write nothing (only the caller's ``wrap_risk`` allows a wrap,
+    and its overflow verdict discards every slot).  The other rows
+    write past the end, each at its own index, so every index is
+    unique."""
+    C = start.shape[0]
+    rows = jnp.arange(C, dtype=jnp.int32)
+    live = (cnt > 0) & (jax.lax.cummin(start) >= 0) & (start < capacity)
+    idx = jnp.where(live, start, capacity + rows)
+    heads = jnp.full((capacity,), -1, jnp.int32).at[idx].set(
+        rows, mode="drop", unique_indices=True)
+    return jnp.clip(jax.lax.cummax(heads), 0, C - 1)
+
+
+def _run_starts(keys_sorted: jax.Array, probe: jax.Array) -> jax.Array:
+    """First position of each probe key in the ascending ``keys_sorted``,
+    ``searchsorted(keys_sorted, probe, side="left")`` for every probe
+    the table holds.  Each run head (a key unlike the one before it)
+    writes its position into a dense table over the vertex ids
+    (``MAX_VERTEX_ID + 1`` entries), and one gather reads it: no binary
+    search.  Other positions and the sentinel tail write past the end,
+    each at its own index, so every index is unique.  A probe the table
+    does not hold reads an arbitrary entry (its join count is 0, so no
+    slot reads it)."""
+    W = keys_sorted.shape[0]
+    pos = jnp.arange(W, dtype=jnp.int32)
+    head = jnp.concatenate([jnp.ones((min(W, 1),), bool),
+                            keys_sorted[1:] != keys_sorted[:-1]])
+    head &= (keys_sorted >= 0) & (keys_sorted <= MAX_VERTEX_ID)
+    idx = jnp.where(head, keys_sorted, MAX_VERTEX_ID + 1 + pos)
+    first = jnp.zeros((MAX_VERTEX_ID + 1,), jnp.int32).at[idx].set(
+        pos, mode="drop", unique_indices=True)
+    return first[jnp.clip(probe, 0, MAX_VERTEX_ID)]
+
+
 def _expand_fixed(bind: jax.Array, valid: jax.Array, col_vals: jax.Array,
                   keys_sorted: jax.Array, payload: jax.Array, capacity: int,
                   paths: Optional[Dict[str, int]] = None
@@ -537,12 +583,14 @@ def _expand_fixed(bind: jax.Array, valid: jax.Array, col_vals: jax.Array,
 
     bind: (C, V) int32 (C need not equal capacity -- after a broadcast
     gather it is num_devices * capacity); valid: (C,) bool; col_vals:
-    (C,) probe keys.  Returns (new_bind (capacity, V), new_payload_col,
-    new_valid, overflow) where overflow is the number of result rows
-    that did NOT fit (int32 scalar, 0 when exact)."""
+    (C,) probe keys, vertex ids.  Returns (new_bind (capacity, V),
+    new_payload_col, new_valid, overflow) where overflow is the number
+    of result rows that did NOT fit (int32 scalar, 0 when exact).  Slot
+    for slot the result of ``kernels.ref.expand_fixed_ref``; its two
+    binary searches are the index maps ``_slot_rows`` and
+    ``_run_starts`` here."""
     C, V = bind.shape
-    probe = jnp.where(valid, col_vals, jnp.iinfo(jnp.int32).max)
-    lo = jnp.searchsorted(keys_sorted, probe, side="left")
+    probe = jnp.where(valid, col_vals, INT32_SENTINEL)
     cnt = jnp.where(valid, _probe_counts(probe, keys_sorted, paths), 0)
     cnt = cnt.astype(jnp.int32)
     # int32 cumsum can wrap past 2^31 total expansion rows and defeat
@@ -554,13 +602,14 @@ def _expand_fixed(bind: jax.Array, valid: jax.Array, col_vals: jax.Array,
                  if C else jnp.bool_(False))
     start = jnp.cumsum(cnt) - cnt                     # output offsets
     total = start[-1] + cnt[-1] if C else jnp.int32(0)
-    # inverse map: output slot t -> source row r
-    t = jnp.arange(capacity)
-    r = jnp.searchsorted(start, t, side="right") - 1
-    r = jnp.clip(r, 0, C - 1)
-    k = t - start[r]
-    ok = (t < total) & (k < cnt[r])
-    src = jnp.clip(lo[r] + k, 0, keys_sorted.shape[0] - 1)
+    _note(paths, "expand_direct")
+    r = _slot_rows(start, cnt, capacity)
+    # slot t < total lies in row r's run (rows with cnt 0 own no slot),
+    # at edge-table position lo[r] + (t - start[r])
+    t = jnp.arange(capacity, dtype=jnp.int32)
+    ok = t < total
+    shift = _run_starts(keys_sorted, probe) - start
+    src = jnp.clip(t + shift[r], 0, keys_sorted.shape[0] - 1)
     new_col = jnp.where(ok, payload[src], -1)
     new_bind = jnp.where(ok[:, None], bind[r], -1)
     over = jnp.maximum(total - capacity, 0).astype(jnp.int32)

@@ -36,6 +36,42 @@ def join_count_ref(left_keys: jax.Array, table_sorted: jax.Array) -> jax.Array:
 
 
 # ----------------------------------------------------------------------
+# Fixed-capacity join expansion by binary search (the oracle of
+# ``core.spmd._expand_fixed``, which builds the same index maps directly)
+# ----------------------------------------------------------------------
+
+def expand_fixed_ref(bind: jax.Array, valid: jax.Array, col_vals: jax.Array,
+                     keys_sorted: jax.Array, payload: jax.Array,
+                     capacity: int):
+    """Join-expand the (C, V) binding table against a sorted (keys ->
+    payload) edge table into ``capacity`` output slots.  Returns
+    (new_bind, new_payload_col, new_valid, overflow rows).  Both index
+    maps -- binding row -> its key run, output slot -> binding row --
+    are ``jnp.searchsorted`` binary searches."""
+    C, V = bind.shape
+    probe = jnp.where(valid, col_vals, jnp.iinfo(jnp.int32).max)
+    lo = jnp.searchsorted(keys_sorted, probe, side="left")
+    cnt = jnp.where(valid, join_count_ref(probe, keys_sorted), 0)
+    cnt = cnt.astype(jnp.int32)
+    wrap_risk = (jnp.max(cnt, initial=0) > (2 ** 31 - 1) // max(C, 1)
+                 if C else jnp.bool_(False))
+    start = jnp.cumsum(cnt) - cnt                     # output offsets
+    total = start[-1] + cnt[-1] if C else jnp.int32(0)
+    # inverse map: output slot t -> source row r
+    t = jnp.arange(capacity)
+    r = jnp.searchsorted(start, t, side="right") - 1
+    r = jnp.clip(r, 0, C - 1)
+    k = t - start[r]
+    ok = (t < total) & (k < cnt[r])
+    src = jnp.clip(lo[r] + k, 0, keys_sorted.shape[0] - 1)
+    new_col = jnp.where(ok, payload[src], -1)
+    new_bind = jnp.where(ok[:, None], bind[r], -1)
+    over = jnp.maximum(total - capacity, 0).astype(jnp.int32)
+    over = jnp.where(wrap_risk, jnp.int32(capacity + 1), over)
+    return new_bind, new_col, ok, over
+
+
+# ----------------------------------------------------------------------
 # Pair semi-join membership: (q_s, q_o) ∈ table pairs?  (the cycle-close
 # probe of the SPMD match loop; int32-safe -- no 42-bit key composition)
 # ----------------------------------------------------------------------

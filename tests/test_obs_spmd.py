@@ -320,3 +320,27 @@ def test_spmd_dedup_takes_the_packed_path(spmd_setup):
         want = host.execute(q)
         assert r.num_rows == want.num_rows
         assert answer_set(r) == answer_set(want)
+
+
+def test_traced_expand_direct_counts_expansion_steps(spmd_setup):
+    """``traced_expand_direct`` equals the expansion steps (join steps
+    that bind a new variable, not pair probes) of every matcher the
+    engine compiled: on a 1-device mesh each traces ``_expand_fixed``
+    once."""
+    from repro.core.query import _connected_edge_order
+    from repro.launch.mesh import make_host_mesh
+    g, _plan = spmd_setup
+    sess = Session(_plan, backend="spmd", mesh=make_host_mesh(1),
+                   metrics_registry=MetricsRegistry())
+    for q in _shape_queries(g, per_shape=1):
+        sess.execute(q)
+    want = 0
+    for edges, _cap, _gen in sess.engine._join_paths:
+        bound = set()
+        for step, ei in enumerate(_connected_edge_order(QueryGraph(edges))):
+            e = edges[ei]
+            known = [v >= 0 or v in bound for v in (e.src, e.dst)]
+            want += step > 0 and not all(known)
+            bound |= {v for v in (e.src, e.dst) if v < 0}
+    assert want > 0
+    assert sess.stats().extra["traced_expand_direct"] == want
