@@ -9,9 +9,9 @@ Everything runs in this one process.  The phases:
 
 1. **device** -- JAX must report a TPU; anything else (a CPU-only host,
    ``JAX_PLATFORMS=cpu``) exits non-zero before any work is done.
-2. **kernels** (one chip only) -- the five Pallas join kernels compiled
-   for the chip, each compared with its ``kernels.ref`` oracle or a
-   numpy one.
+2. **kernels** (one chip only) -- the three Pallas join kernels
+   (``semijoin``, ``join_count``, ``pair_semijoin``) compiled for the
+   chip, each compared with its ``kernels.ref`` oracle or a numpy one.
 3. **plan** -- a WatDiv-shaped graph (``generate_watdiv``), a 2000-query
    workload and a vertical 4-site ``PartitionPlan``.
 4. **serve** -- ``Session(plan, backend="spmd")`` behind
@@ -119,12 +119,8 @@ def phase_device(jax, chips: int):
     return devs
 
 
-#: output capacity of the fused-join kernel case
-FUSED_CAPACITY = 4096
-
-
 def kernel_cases(seed: int):
-    """The five join kernels at the smoke's shapes: name -> (op, numpy
+    """The three join kernels at the smoke's shapes: name -> (op, numpy
     operands).  ``tests/test_tpu_compile.py`` compiles the same calls
     for a described chip."""
     import numpy as np
@@ -136,8 +132,6 @@ def kernel_cases(seed: int):
         return rng.integers(0, hi, shape).astype(np.int32)
 
     table = np.sort(ids(1 << 16, 65536))
-    bind = ids(64, 8192, 3)
-    fbind = ids(4096, 2048, 2)
     return {
         "semijoin": (functools.partial(ops.semijoin, jit_safe=True),
                      (ids(1 << 16, 16384), table)),
@@ -147,40 +141,11 @@ def kernel_cases(seed: int):
                                             jit_safe=True),
                           (ids(512, 16384), ids(512, 16384),
                            ids(512, 32768), ids(512, 32768))),
-        "dedup_rows": (ops.dedup_rows, (bind, rng.random(8192) < 0.9)),
-        "fused_join": (functools.partial(ops.fused_join,
-                                         capacity=FUSED_CAPACITY),
-                       (fbind, rng.random(2048) < 0.9, fbind[:, 0],
-                        np.sort(ids(1 << 14, 8192)), ids(1 << 20, 8192))),
     }
 
 
-def first_occurrence(bind, valid):
-    """Keep flag of the first valid occurrence of every distinct row."""
-    import numpy as np
-    idx = np.flatnonzero(valid)
-    keep = np.zeros(len(valid), bool)
-    if idx.size:
-        _, first = np.unique(bind[idx], axis=0, return_index=True)
-        keep[idx[first]] = True
-    return keep
-
-
-def fused_join_oracle(bind, valid, probe, keys, pay, capacity):
-    """(sorted result rows of bind + payload, overflow) of dedup ->
-    expand against the sorted ``keys`` -> ``pay`` table."""
-    import numpy as np
-    rows = np.flatnonzero(first_occurrence(bind, valid))
-    lo = np.searchsorted(keys, probe[rows], side="left")
-    cnt = np.searchsorted(keys, probe[rows], side="right") - lo
-    src = np.repeat(rows, cnt)
-    at = np.repeat(lo - np.cumsum(cnt) + cnt, cnt) + np.arange(cnt.sum())
-    out = np.column_stack([bind[src], pay[at]])
-    return out[np.lexsort(out.T[::-1])], max(int(cnt.sum()) - capacity, 0)
-
-
 def phase_kernels(seed: int) -> None:
-    """The five join kernels, compiled (never interpreted) on the chip,
+    """The three join kernels, compiled (never interpreted) on the chip,
     against the ``kernels.ref`` oracles and numpy on seeded data."""
     import numpy as np
 
@@ -209,15 +174,6 @@ def phase_kernels(seed: int) -> None:
     same("pair_semijoin", got,
          np.isin((qs.astype(np.int64) << 32) | qo,
                  (ts.astype(np.int64) << 32) | to))
-    got, (bind, valid) = run("dedup_rows")
-    same("dedup_rows", got, first_occurrence(bind, valid))
-    (nb, nc, nv, over), args = run("fused_join")
-    want, want_over = fused_join_oracle(*args, FUSED_CAPACITY)
-    nv = np.asarray(nv)
-    out = np.column_stack([np.asarray(nb)[nv], np.asarray(nc)[nv]])
-    check(want_over == 0 and int(over) == 0,
-          "fused_join smoke case overflowed")
-    same("fused_join", out[np.lexsort(out.T[::-1])], want)
 
 
 def phase_plan(triples: int, seed: int):
@@ -311,7 +267,8 @@ def phase_serve(g, plan, queries, chips: int, meter):
         f"{time.perf_counter() - t0:.3f} s")
     engine = session.engine
     store = engine.store
-    for name in ("s", "p", "o", "csr_sub_s", "owned"):
+    for name in ("csr_sub_s", "csr_sub_o", "csr_obj_o", "csr_obj_s",
+                 "csr_offs", "owned"):
         arr = getattr(store, name)
         shards = arr.addressable_shards
         check(len(shards) == chips
@@ -373,7 +330,7 @@ def phase_serve(g, plan, queries, chips: int, meter):
              if k.startswith("traced_")}
     log("join steps traced per path: " + json.dumps(paths, sort_keys=True))
     log(f"kernels on: {extra['pallas_join_kernels']:.0f} of the match "
-        f"loop's four ({', '.join(TPU_KERNELS)} by default), interpret "
+        f"loop's two ({', '.join(TPU_KERNELS)}), interpret "
         f"mode: {bool(extra['pallas_interpret'])}; capacity retries "
         f"{extra['capacity_retries']:.0f}, overflow events "
         f"{extra['overflow_events']:.0f}, routed queries "

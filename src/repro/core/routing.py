@@ -112,8 +112,6 @@ def route_prop_complete(store, prop: int,
     completeness is only required of the devices the query actually
     runs on).  Properties outside the metadata range are trivially
     complete."""
-    if store.prop_dev_distinct is None:
-        return False
     if not (0 <= prop < store.prop_union_rows.shape[0]):
         return True
     union = store.prop_union_rows[prop]
@@ -124,8 +122,6 @@ def _prop_dup_free(store, prop: int, members: Sequence[int]) -> bool:
     """Stored rows == distinct edge ids of ``prop`` on every member
     (striping ranks over duplicated rows could drop a seed, same caveat
     as ``plan_seed_decimation``)."""
-    if store.prop_dev_rows is None:
-        return False
     if not (0 <= prop < store.prop_dev_rows.shape[1]):
         return True
     return all(store.prop_dev_rows[j, prop]
@@ -137,13 +133,12 @@ def plan_route(store, pattern: QueryGraph, *,
     """Compute the ``RoutePlan`` for matching ``pattern`` over
     ``store`` (see module docstring for the membership / rendezvous /
     seed-balancing rules).  Falls back to the whole mesh -- routing as
-    a no-op -- when residency metadata is unavailable or the pattern
-    carries wildcard properties."""
+    a no-op -- when the pattern has no edges or carries wildcard
+    properties."""
     m = int(store.num_sites)
     key = repr(tuple(pattern.edges))
     props = [e.prop for e in pattern.edges]
-    if (store.prop_dev_rows is None or not props
-            or any(p < 0 for p in props)):
+    if not props or any(p < 0 for p in props):
         members = tuple(range(m))
         ranks = tuple(range(m))
         return RoutePlan(members, m, ranks, False, False, False, 0)
@@ -180,8 +175,7 @@ def plan_route(store, pattern: QueryGraph, *,
     seed_ranks = tuple(rank_of.get(j, -1) for j in range(m))
 
     seed_rows = 0
-    if decimate and store.prop_union_rows is not None \
-            and 0 <= p0 < store.prop_union_rows.shape[0]:
+    if decimate and 0 <= p0 < store.prop_union_rows.shape[0]:
         union = int(store.prop_union_rows[p0])
         seed_rows = -(-union // max(len(members), 1))
     return RoutePlan(members, m, seed_ranks, decimate, rendezvous,

@@ -66,12 +66,12 @@ or ``max_capacity`` is hit, which raises instead of truncating.
 
 The expansion probes (join multiplicities per binding row) and the
 cycle-close pair probe run through the blocked Pallas kernels in
-``repro.kernels`` on TPU (``TPU_KERNELS``); the gathered-row dedup and
-the fused dedup->expand->filter join have Pallas kernels too, used only
-when forced.  The ``kernels.ref`` jnp oracles and the jnp composition
-are the CPU path (``REPRO_SPMD_PALLAS=1/0`` forces every kernel on or
-off).  Which path each join step traced is counted per compiled matcher
-(``JOIN_PATHS``, ``stats().extra["traced_*"]``).
+``repro.kernels`` on TPU (``TPU_KERNELS``) and through their
+``kernels.ref`` jnp oracles elsewhere (``REPRO_SPMD_PALLAS=1/0`` forces
+the two kernels on or off).  A gathered step dedups its rows with a
+lexicographic sort (``_dedup_padded``) and expands them with
+``_expand_fixed``.  Which path each join step traced is counted per
+compiled matcher (``JOIN_PATHS``, ``stats().extra["traced_*"]``).
 """
 from __future__ import annotations
 
@@ -104,8 +104,9 @@ from .routing import RoutePlan, plan_route
 class SiteStore:
     """Per-site edge storage, padded to uniform shape for SPMD.
 
-    s/p/o: (num_sites, E_max) int32, padded with -1 (never matches).
-    sorted by (p, s) within each site so searchsorted probes work.
+    Each site's edges are sorted by (p, s, o) and packed into
+    per-property tables (below); ``e_max`` is the most edges any site
+    holds, rounded up to ``pad_multiple``.
 
     ``build`` also derives the static per-property residency metadata
     the communication planner reads (host-side numpy, trace-time
@@ -132,17 +133,16 @@ class SiteStore:
     logical sites folded onto one device.  For such a step no
     inter-device shipping is needed at all.
 
-    ``build`` additionally packs **CSR per-property edge tables** (the
-    join hot-path layout): because rows are stored sorted by
-    (p, s, o), each property's edges form one contiguous, subject-sorted
-    run; ``csr_sub_s``/``csr_sub_o`` hold those runs (key = subject,
+    The device holds **CSR per-property edge tables** (the join
+    hot-path layout): because rows are sorted by (p, s, o), each
+    property's edges form one contiguous, subject-sorted run;
+    ``csr_sub_s``/``csr_sub_o`` hold those runs (key = subject,
     payload = object), ``csr_obj_o``/``csr_obj_s`` hold the
     object-sorted counterpart from a second (p, o, s) sort, and
     ``csr_offs`` (m, P+1) holds the per-device run offsets.  The match
     loop slices one property's run per join step (a
-    ``lax.dynamic_slice`` window sized by static residency metadata)
-    instead of re-running ``argsort``/``p == prop`` scans over the full
-    padded (m, e_max) columns on every traced step.  Arrays are padded
+    ``lax.dynamic_slice`` window sized by static residency metadata),
+    so no traced step sorts or scans a whole site.  Arrays are padded
     ``csr_pad`` rows past the last run so a window never clamps into a
     neighbouring property.
 
@@ -152,22 +152,19 @@ class SiteStore:
     and the matcher's ``shard_map`` consumes them without a reshard;
     without it they land on the default device.
     """
-    s: jax.Array
-    p: jax.Array
-    o: jax.Array
     num_sites: int
     e_max: int
-    prop_dev_rows: Optional[np.ndarray] = None       # (m, P) int64
-    prop_dev_distinct: Optional[np.ndarray] = None   # (m, P) int64
-    prop_union_rows: Optional[np.ndarray] = None     # (P,) int64
-    csr_sub_s: Optional[jax.Array] = None   # (m, e_max + csr_pad) int32
-    csr_sub_o: Optional[jax.Array] = None
-    csr_obj_o: Optional[jax.Array] = None
-    csr_obj_s: Optional[jax.Array] = None
-    csr_offs: Optional[jax.Array] = None    # (m, P + 1) int32
-    csr_pad: int = 0
-    prop_dev_owned: Optional[np.ndarray] = None      # (m, P) int64
-    owned: Optional[jax.Array] = None       # (m, e_max + csr_pad) bool
+    prop_dev_rows: np.ndarray       # (m, P) int64
+    prop_dev_distinct: np.ndarray   # (m, P) int64
+    prop_union_rows: np.ndarray     # (P,) int64
+    csr_sub_s: jax.Array            # (m, e_max + csr_pad) int32
+    csr_sub_o: jax.Array
+    csr_obj_o: jax.Array
+    csr_obj_s: jax.Array
+    csr_offs: jax.Array             # (m, P + 1) int32
+    csr_pad: int
+    prop_dev_owned: np.ndarray      # (m, P) int64
+    owned: jax.Array                # (m, e_max + csr_pad) bool
 
     @staticmethod
     def build(graph: RDFGraph, site_edge_ids: Sequence[np.ndarray],
@@ -177,9 +174,6 @@ class SiteStore:
         m = len(site_edge_ids)
         e_max = max((len(e) for e in site_edge_ids), default=1)
         e_max = int(np.ceil(max(e_max, 1) / pad_multiple) * pad_multiple)
-        S = np.full((m, e_max), -1, np.int32)
-        Pm = np.full((m, e_max), -1, np.int32)
-        O = np.full((m, e_max), -1, np.int32)
         n_props = graph.num_properties
         dev_rows = np.zeros((m, n_props), np.int64)
         dev_distinct = np.zeros((m, n_props), np.int64)
@@ -195,7 +189,6 @@ class SiteStore:
             s, p, o = graph.s[eids], graph.p[eids], graph.o[eids]
             order = np.lexsort((o, s, p))
             n = len(eids)
-            S[j, :n], Pm[j, :n], O[j, :n] = s[order], p[order], o[order]
             dev_rows[j] = np.bincount(p, minlength=n_props)[:n_props]
             dev_distinct[j] = np.bincount(
                 graph.p[np.unique(eids)], minlength=n_props)[:n_props]
@@ -205,7 +198,7 @@ class SiteStore:
             owner[eids[claim]] = j
             dev_owned[j] = np.bincount(
                 p[claim], minlength=n_props)[:n_props]
-            per_site.append((s, p, o, n, claim[order]))
+            per_site.append((s, p, o, order, claim[order]))
         resident = np.unique(np.concatenate(
             [np.zeros(0, np.int64)]
             + [np.asarray(e, np.int64) for e in site_edge_ids]))
@@ -225,19 +218,18 @@ class SiteStore:
         obj_s = np.full((m, width), -1, np.int32)
         offs = np.zeros((m, n_props + 1), np.int32)
         owned = np.zeros((m, width), bool)
-        for j, (s, p, o, n, claim_sorted) in enumerate(per_site):
-            sub_s[j, :n], sub_o[j, :n] = S[j, :n], O[j, :n]
+        for j, (s, p, o, order, claim_sorted) in enumerate(per_site):
+            n = len(order)
+            sub_s[j, :n], sub_o[j, :n] = s[order], o[order]
             owned[j, :n] = claim_sorted
             order_o = np.lexsort((s, o, p))
             obj_o[j, :n], obj_s[j, :n] = o[order_o], s[order_o]
-            offs[j, 1:] = np.cumsum(
-                np.bincount(p, minlength=n_props)[:n_props])
+            offs[j, 1:] = np.cumsum(dev_rows[j])
         def put(a: np.ndarray) -> jax.Array:
             return (jnp.asarray(a) if sharding is None
                     else jax.device_put(a, sharding))
 
-        return SiteStore(put(S), put(Pm), put(O),
-                         m, e_max, dev_rows, dev_distinct, union,
+        return SiteStore(m, e_max, dev_rows, dev_distinct, union,
                          put(sub_s), put(sub_o), put(obj_o), put(obj_s),
                          put(offs), pad, dev_owned, put(owned))
 
@@ -246,8 +238,6 @@ class SiteStore:
         step on it needs no inter-device shipping).  Properties outside
         the metadata range (or resident nowhere) are trivially
         complete."""
-        if self.prop_dev_distinct is None:
-            return False
         if not (0 <= prop < self.prop_union_rows.shape[0]):
             return True
         return bool(np.all(self.prop_dev_distinct[:, prop]
@@ -256,8 +246,7 @@ class SiteStore:
     def prop_rows(self, prop: int) -> Tuple[int, int]:
         """(total stored rows across devices, max rows on any device)
         for ``prop`` -- the static size of the edge-shipping side."""
-        if (self.prop_dev_rows is None
-                or not 0 <= prop < self.prop_dev_rows.shape[1]):
+        if not 0 <= prop < self.prop_dev_rows.shape[1]:
             return 0, 0
         col = self.prop_dev_rows[:, prop]
         return int(col.sum()), int(col.max(initial=0))
@@ -274,8 +263,7 @@ class SiteStore:
         """Distinct edges of ``prop`` resident anywhere -- the rows an
         edge-shipping step puts on the wire (each resident edge ships
         from its one owning device)."""
-        if (self.prop_union_rows is None
-                or not 0 <= prop < self.prop_union_rows.shape[0]):
+        if not 0 <= prop < self.prop_union_rows.shape[0]:
             return 0
         return int(self.prop_union_rows[prop])
 
@@ -285,20 +273,16 @@ class SiteStore:
         the planner's edge-gather buffers (``plan_step_comm``) --
         smaller than ``prop_window`` whenever replication stores the
         same edge on several devices, since only the owner ships it."""
-        if (self.prop_dev_owned is None
-                or not 0 <= prop < self.prop_dev_owned.shape[1]):
+        if not 0 <= prop < self.prop_dev_owned.shape[1]:
             return 8
         per_dev = int(self.prop_dev_owned[:, prop].max(initial=0))
         return int(np.ceil(max(per_dev, 1) / 8) * 8)
 
-    def csr_arrays(self) -> Optional[Tuple[jax.Array, ...]]:
+    def csr_arrays(self) -> Tuple[jax.Array, ...]:
         """The packed per-property tables as one tuple of device
         arrays (subject-sorted keys/payload, object-sorted
-        keys/payload, offsets, owned-row flags), or ``None`` on a
-        store built without them -- the matcher falls back to per-step
-        masked ``argsort`` tables."""
-        if self.csr_offs is None:
-            return None
+        keys/payload, offsets, owned-row flags): what a matcher of
+        ``make_spmd_matcher`` takes."""
         return (self.csr_sub_s, self.csr_sub_o, self.csr_obj_o,
                 self.csr_obj_s, self.csr_offs, self.owned)
 
@@ -421,8 +405,7 @@ def plan_seed_decimation(store: SiteStore, pattern: QueryGraph) -> bool:
     prop = pattern.edges[order[0]].prop
     if not store.prop_shard_complete(prop):
         return False
-    if store.prop_dev_rows is not None \
-            and 0 <= prop < store.prop_dev_rows.shape[1] \
+    if 0 <= prop < store.prop_dev_rows.shape[1] \
             and not np.array_equal(store.prop_dev_rows[:, prop],
                                    store.prop_dev_distinct[:, prop]):
         return False
@@ -433,37 +416,17 @@ def plan_seed_decimation(store: SiteStore, pattern: QueryGraph) -> bool:
 # Local (per-site) fixed-capacity pattern matching
 # ----------------------------------------------------------------------
 
-def _edge_table_for_prop(s: jax.Array, p: jax.Array, o: jax.Array,
-                         prop: int) -> Tuple[jax.Array, jax.Array]:
-    """(keys, payload) of this property's edges, sorted by subject;
-    non-matching rows pushed to the +inf sentinel.  Fallback path for
-    stores without CSR-packed tables -- the packed path slices a
-    pre-sorted window instead (see ``SiteStore`` docstring)."""
-    sel = p == prop
-    keys = jnp.where(sel, s, INT32_SENTINEL)
-    order = jnp.argsort(keys)
-    return keys[order], o[order]
-
-
-#: The join kernels the match loop calls: ``join_count``, ``pair_semijoin``,
-#: ``dedup_rows``, ``fused_join``.  All four compile for TPU v5e
+#: The Pallas kernels the match loop calls: the blocked expansion-size
+#: and cycle-close probes.  Both compile for TPU v5e
 #: (``tests/test_tpu_compile.py``).
-MATCH_KERNELS = ("join_count", "pair_semijoin", "dedup_rows", "fused_join")
-
-#: The kernels on by default on TPU.  ``dedup_rows`` and ``fused_join`` are
-#: off there by decision: they are serial scalar programs whose operands
-#: all live in SMEM (1 MiB on a v5e core), far less than a gathered step
-#: of the served capacity tiers, and no chip run shows them beating the
-#: jnp composition at a size that fits.
 TPU_KERNELS = ("join_count", "pair_semijoin")
 
 
 def _use_pallas_probes(kernel: str) -> bool:
-    """Is the Pallas ``kernel`` (one of ``MATCH_KERNELS``) on?  On TPU the
-    ``TPU_KERNELS``; jnp oracles elsewhere.  The env knob
-    ``REPRO_SPMD_PALLAS`` forces all four on or off (tests exercise the
-    kernel path in interpret mode on CPU through it; forced on a chip,
-    the SMEM kernels compile only for tables that fit SMEM)."""
+    """Is the Pallas ``kernel`` (one of ``TPU_KERNELS``) on?  On TPU,
+    yes; elsewhere its jnp oracle runs.  The env knob
+    ``REPRO_SPMD_PALLAS`` forces both on or off (tests exercise the
+    kernel path in interpret mode on CPU through it)."""
     env = os.environ.get("REPRO_SPMD_PALLAS")
     if env is not None:
         return env not in ("0", "false", "")
@@ -478,11 +441,7 @@ def _use_pallas_probes(kernel: str) -> bool:
 #:   probes traced through the blocked kernel / the searchsorted oracle;
 #: * ``pair_semijoin_kernel`` / ``pair_semijoin_jnp`` -- cycle-close
 #:   probes, likewise;
-#: * ``hash_dedup_kernel`` / ``lexsort_dedup`` -- gathered-row dedups
-#:   traced through the hash kernel / the jnp lexsort;
-#: * ``fused_join_kernel`` / ``jnp_join`` -- binding-gather expansion
-#:   steps traced through the fused kernel / the dedup + ``_expand_fixed``
-#:   composition;
+#: * ``lexsort_dedup`` -- gathered-row dedups (``_dedup_padded``);
 #: * ``expand_direct`` -- ``_expand_fixed`` calls, each mapping rows and
 #:   output slots by scatter and running max (no binary search).
 #:
@@ -490,8 +449,7 @@ def _use_pallas_probes(kernel: str) -> bool:
 #: counts the paths of both.
 JOIN_PATHS = ("join_count_kernel", "join_count_jnp",
               "pair_semijoin_kernel", "pair_semijoin_jnp",
-              "hash_dedup_kernel", "lexsort_dedup",
-              "fused_join_kernel", "jnp_join", "expand_direct")
+              "lexsort_dedup", "expand_direct")
 
 
 def _note(paths: Optional[Dict[str, int]], name: str) -> None:
@@ -626,22 +584,14 @@ def _dedup_padded(bind: jax.Array, valid: jax.Array,
     (replicated fragments); deduping before expansion keeps capacity
     pressure at the number of *distinct* partial matches.
 
-    With the ``dedup_rows`` kernel on (``REPRO_SPMD_PALLAS=1``) this
-    runs the open-addressed hash-dedup Pallas kernel -- O(n) inserts
-    with full-row compare on collision, keep mask in place -- instead of
-    the O(n log n) column-wise lexicographic sort (``lexsort_passes``).
-    That sort is the default and the implementation of record: rows
-    come back sorted there, in place on the kernel path; no caller
-    observes the order."""
+    A column-wise lexicographic sort (``lexsort_passes``) brings equal
+    rows together; rows come back sorted, which no caller observes.
+    ``kernels.ref.dedup_rows_ref`` is its oracle."""
     C, V = bind.shape
     if V == 0:
         keep = jnp.zeros_like(valid).at[0].set(valid.any())
         return bind, keep
-    from ..kernels.ops import dedup_rows, lexsort_passes
-    if _use_pallas_probes("dedup_rows"):
-        _note(paths, "hash_dedup_kernel")
-        keep = dedup_rows(bind, valid)
-        return jnp.where(keep[:, None], bind, -1), keep
+    from ..kernels.ops import lexsort_passes
     _note(paths, "lexsort_dedup")
     keys = tuple(bind[:, v] for v in range(V - 1, -1, -1)) \
         + ((~valid).astype(jnp.int32),)
@@ -713,22 +663,24 @@ def pattern_var_order(pattern: QueryGraph) -> List[int]:
     return _var_col_trace(pattern)[0]
 
 
-def _match_shard(s: jax.Array, p: jax.Array, o: jax.Array,
+def _match_shard(csr: Tuple[jax.Array, ...], prop_windows: Dict[int, int],
                  pattern: QueryGraph, capacity: int,
                  axis: Optional[str] = None,
                  comm: Optional[Sequence[StepComm]] = None,
                  axis_size: int = 1, seed_decimate: bool = False,
-                 csr: Optional[Tuple[jax.Array, ...]] = None,
-                 prop_windows: Optional[Dict[int, int]] = None,
                  route_ranks: Optional[Sequence[int]] = None,
                  route_width: int = 0,
                  paths: Optional[Dict[str, int]] = None
                  ) -> Tuple[jax.Array, jax.Array, List[int], jax.Array,
                             jax.Array, jax.Array]:
-    """Match ``pattern`` over one shard's edge table, padded to
-    ``capacity`` rows.  Returns (bindings (capacity, V), valid,
-    var_order, overflow-row-count, per-step decisions, per-step
-    shipped-row counts).
+    """Match ``pattern`` over one shard's packed edge tables, padded to
+    ``capacity`` rows.  ``csr`` is this shard's slice of the
+    ``SiteStore.csr_arrays()`` tuple, and ``prop_windows`` the static
+    window rows of every property of the pattern
+    (``SiteStore.prop_window``): every per-step edge table is a
+    ``lax.dynamic_slice`` of a pre-sorted property run.  Returns
+    (bindings (capacity, V), valid, var_order, overflow-row-count,
+    per-step decisions, per-step shipped-row counts).
 
     With ``axis`` set (inside shard_map) every join step is a broadcast
     join whose shipping is chosen by ``comm`` (one ``StepComm`` per join
@@ -773,18 +725,10 @@ def _match_shard(s: jax.Array, p: jax.Array, o: jax.Array,
     under ``jax.named_scope(f"step{j}")``, so its operations carry the
     step in their HLO metadata and in a profiler trace.
 
-    ``csr`` (the ``SiteStore.csr_arrays()`` tuple, per-device slices)
-    plus ``prop_windows`` (static per-property window rows,
-    ``SiteStore.prop_window``) switch every per-step edge-table build
-    to a ``lax.dynamic_slice`` of the pre-sorted property run -- no
-    per-step ``argsort`` or ``p == prop`` scan in the trace.  With
-    ``csr=None`` the original masked-column builds are used
-    (``local_match`` compatibility path, directly-built stores).
-
     ``paths`` (a dict) receives the trace-time join-path counts
     (``JOIN_PATHS``).
     """
-    from ..kernels.ops import compact_rows, fused_join
+    from ..kernels.ops import compact_rows
     order = _connected_edge_order(pattern)
     edges = pattern.edges
     var_cols: List[int] = []
@@ -793,7 +737,7 @@ def _match_shard(s: jax.Array, p: jax.Array, o: jax.Array,
     def col_idx(v: int) -> int:
         return var_cols.index(v)
 
-    n_props = int(csr[4].shape[-1]) - 1 if csr is not None else 0
+    n_props = int(csr[4].shape[-1]) - 1
 
     def csr_window(prop: int, subject_side: bool,
                    size: Optional[int] = None, pay_fill: int = -1
@@ -807,7 +751,7 @@ def _match_shard(s: jax.Array, p: jax.Array, o: jax.Array,
         same formula that sized the planner's gather buffers)."""
         sub_s_d, sub_o_d, obj_o_d, obj_s_d, offs_d = csr[:5]
         if size is None:
-            size = (prop_windows or {}).get(prop, 8)
+            size = prop_windows[prop]
         if not 0 <= prop < n_props:   # never stored: empty static table
             return (jnp.full((size,), imax, jnp.int32),
                     jnp.full((size,), pay_fill, jnp.int32), jnp.int32(0))
@@ -850,19 +794,11 @@ def _match_shard(s: jax.Array, p: jax.Array, o: jax.Array,
             d_known = e.dst >= 0 or e.dst in var_cols
 
             if step == 0:
-                # initialize from the property's local edge list.  With CSR
-                # tables the candidate rows are the property's packed run (a
-                # static window, identically (s, o)-ordered on every device
-                # -- the same order the (p, s, o)-sorted fallback scan
-                # yields, so seed decimation stripes identically); without
-                # them, scan the full padded columns.
-                if csr is not None:
-                    seed_s, seed_o, n_live = csr_window(e.prop, True)
-                    live = jnp.arange(seed_s.shape[0], dtype=jnp.int32) \
-                        < n_live
-                else:
-                    seed_s, seed_o, live = s, o, (p == e.prop)
-                sel = live
+                # initialize from the property's packed run: a static
+                # window, identically (s, o)-ordered on every device, so
+                # seed decimation stripes identically
+                seed_s, seed_o, n_live = csr_window(e.prop, True)
+                sel = jnp.arange(seed_s.shape[0], dtype=jnp.int32) < n_live
                 if e.src >= 0:
                     sel &= seed_s == e.src
                 if e.dst >= 0:
@@ -919,11 +855,8 @@ def _match_shard(s: jax.Array, p: jax.Array, o: jax.Array,
 
             # -- shared builders for this step (all shapes static) ----------
             def local_pair_tables():
-                if csr is not None:
-                    t_s, t_o, _n = csr_window(e.prop, True, pay_fill=imax)
-                    return t_s, t_o
-                sel_ = p == e.prop
-                return jnp.where(sel_, s, imax), jnp.where(sel_, o, imax)
+                t_s, t_o, _n = csr_window(e.prop, True, pay_fill=imax)
+                return t_s, t_o
 
             def fresh_prop_tables():
                 # the edge-shipping side: this device's OWNED rows of the
@@ -935,14 +868,10 @@ def _match_shard(s: jax.Array, p: jax.Array, o: jax.Array,
                 # rows on the wire, not the padded window, and no
                 # replicated duplicates to re-expand.  Compacting a
                 # subsequence of the (s, o)-sorted run keeps it sorted;
-                # the imax fill sorts last, as before.
-                if csr is not None:
-                    fk, fp, n_run = csr_window(e.prop, True, pay_fill=imax)
-                    ow = owned_run_window(e.prop, fk.shape[0], n_run)
-                    (ls, lo_), _ = compact_rows(ow, (fk, fp), sc.gather_cap)
-                else:
-                    (ls, lo_), _ = compact_rows(p == e.prop, (s, o),
-                                                sc.gather_cap)
+                # the imax fill sorts last.
+                fk, fp, n_run = csr_window(e.prop, True, pay_fill=imax)
+                ow = owned_run_window(e.prop, fk.shape[0], n_run)
+                (ls, lo_), _ = compact_rows(ow, (fk, fp), sc.gather_cap)
                 return (jax.lax.all_gather(ls, axis, tiled=True),
                         jax.lax.all_gather(lo_, axis, tiled=True))
 
@@ -1055,38 +984,16 @@ def _match_shard(s: jax.Array, p: jax.Array, o: jax.Array,
 
                 def local_table():
                     # the property's sorted (key -> payload) table: a CSR
-                    # window slice when packed tables are available (keys
-                    # already sorted, no trace-time argsort), the masked
-                    # argsort build otherwise
-                    if csr is not None:
-                        keys, payload, _n = csr_window(e.prop, s_known)
-                        return keys, payload
-                    if s_known:
-                        return _edge_table_for_prop(s, p, o, e.prop)
-                    sel_ = p == e.prop
-                    okeys = jnp.where(sel_, o, imax)
-                    oorder = jnp.argsort(okeys)
-                    return okeys[oorder], s[oorder]
+                    # window slice, keys already sorted
+                    keys, payload, _n = csr_window(e.prop, s_known)
+                    return keys, payload
 
                 def exp_via_gather(bt, vt):
-                    # the fused Pallas kernel runs dedup -> expand -> filter
-                    # in one SMEM pass over the raw gathered table; the
-                    # composition below (exact-dedup then _expand_fixed) is
-                    # the default path and the semantics of record
-                    gb = jax.lax.all_gather(bt, axis, tiled=True)
-                    gv = jax.lax.all_gather(vt, axis, tiled=True)
-                    shipped = gv.sum().astype(jnp.int32)
+                    gb, gv, shipped = gathered_bindings(bt, vt)
                     keys, payload = local_table()
-                    if _use_pallas_probes("fused_join"):
-                        _note(paths, "fused_join_kernel")
-                        nb, nc, nv, over = fused_join(
-                            gb, gv, probe_vals(gb), keys, payload, capacity)
-                    else:
-                        _note(paths, "jnp_join")
-                        gb, gv = _dedup_padded(gb, gv, paths)
-                        nb, nc, nv, over = _expand_fixed(
-                            gb, gv, probe_vals(gb), keys, payload, capacity,
-                            paths)
+                    nb, nc, nv, over = _expand_fixed(
+                        gb, gv, probe_vals(gb), keys, payload, capacity,
+                        paths)
                     return nb, nc, nv, over, shipped
 
                 def exp_via_gather_c(bt, vt):
@@ -1132,40 +1039,26 @@ def _match_shard(s: jax.Array, p: jax.Array, o: jax.Array,
     return bind, valid, var_cols, jnp.maximum(ovf, 0), dec_arr, row_arr
 
 
-def local_match(s: jax.Array, p: jax.Array, o: jax.Array,
-                pattern: QueryGraph, capacity: int
-                ) -> Tuple[jax.Array, jax.Array, List[int]]:
-    """Shard-local matching (no collectives): compatibility wrapper over
-    ``_match_shard`` returning (bindings, valid, var_order)."""
-    bind, valid, cols, _ovf, _dec, _rows = _match_shard(s, p, o, pattern,
-                                                        capacity)
-    return bind, valid, cols
-
-
 # ----------------------------------------------------------------------
 # shard_map distributed execution
 # ----------------------------------------------------------------------
 
 def make_spmd_matcher(mesh: Mesh, axis: str, pattern: QueryGraph,
-                      capacity: int,
+                      capacity: int, prop_windows: Dict[int, int],
                       comm: Optional[Sequence[StepComm]] = None,
                       seed_decimate: bool = False,
-                      use_csr: bool = False,
-                      prop_windows: Optional[Dict[int, int]] = None,
                       route_ranks: Optional[Sequence[int]] = None,
                       route_width: int = 0,
                       join_paths: Optional[Dict[str, int]] = None):
-    """Build a jitted SPMD function: site-sharded (s,p,o) -> gathered
-    binding tables (num_sites * capacity, V), validity mask, the
-    per-device overflow row count (num_sites,), and the planner's
-    per-join-step decision / shipped-row vectors (replicated).
-
-    With ``use_csr=True`` the function takes the six
-    ``SiteStore.csr_arrays()`` tables as additional sharded arguments
-    (call ``fn(store.s, store.p, store.o, *store.csr_arrays())``) and
-    ``prop_windows`` must carry the static per-property window sizes
-    (``SiteStore.prop_window``); the match loop then slices pre-sorted
-    property runs instead of rebuilding tables per step.
+    """Build a jitted SPMD function: the six site-sharded
+    ``SiteStore.csr_arrays()`` tables (call
+    ``fn(*store.csr_arrays())``) -> gathered binding tables
+    (num_sites * capacity, V), validity mask, the per-device overflow
+    row count (num_sites,), and the planner's per-join-step decision /
+    shipped-row vectors (replicated).  ``prop_windows`` carries the
+    static window rows of every property of ``pattern``
+    (``SiteStore.prop_window``); the match loop slices pre-sorted
+    property runs of that size.
 
     Every join step inside ``_match_shard`` broadcast-joins with the
     shipping mode chosen by ``comm`` (see ``plan_step_comm``; ``None``
@@ -1173,8 +1066,6 @@ def make_spmd_matcher(mesh: Mesh, axis: str, pattern: QueryGraph,
     results'); those bytes are what the §Roofline collective term
     counts.  A non-zero overflow entry means that device's table filled
     and the caller must retry at a higher capacity for an exact answer.
-    Dynamic (edge-shipping) steps compact each device's *owned* rows
-    from the CSR owned flags, so they require ``use_csr=True``.
 
     ``seed_decimate=True`` asserts step 0's property is shard-complete
     (``plan_seed_decimation``): the seed rows are then striped across
@@ -1195,22 +1086,14 @@ def make_spmd_matcher(mesh: Mesh, axis: str, pattern: QueryGraph,
     # fast path; the mesh size is static at trace time.
     m = int(np.prod(mesh.devices.shape))
     step_axis = axis if m > 1 else None
-    n_in = 9 if use_csr else 3
-    if (not use_csr and comm is not None
-            and any(sc.mode == "dynamic" for sc in comm)):
-        raise ValueError(
-            "edge-shipping comm specs need a CSR-packed store: the "
-            "shipped side is the per-device owned rows, which only the "
-            "CSR owned flags identify (SiteStore.build packs them)")
 
     def per_site(*arrs):
-        s, p, o = (a[0] for a in arrs[:3])
-        csr = tuple(a[0] for a in arrs[3:]) if use_csr else None
+        csr = tuple(a[0] for a in arrs)
         bind, valid, cols, ovf, dec, rows = _match_shard(
-            s, p, o, pattern, capacity, axis=step_axis, comm=comm,
-            axis_size=m, seed_decimate=seed_decimate, csr=csr,
-            prop_windows=prop_windows, route_ranks=route_ranks,
-            route_width=route_width, paths=join_paths)
+            csr, prop_windows, pattern, capacity, axis=step_axis,
+            comm=comm, axis_size=m, seed_decimate=seed_decimate,
+            route_ranks=route_ranks, route_width=route_width,
+            paths=join_paths)
         with jax.named_scope("final_gather"):
             g_bind = jax.lax.all_gather(bind, axis, tiled=True)
             g_valid = jax.lax.all_gather(valid, axis, tiled=True)
@@ -1218,17 +1101,14 @@ def make_spmd_matcher(mesh: Mesh, axis: str, pattern: QueryGraph,
         return g_bind, g_valid, g_ovf, dec, rows
 
     fn = jax.shard_map(per_site, mesh=mesh,
-                       in_specs=(P(axis, None),) * n_in,
+                       in_specs=(P(axis, None),) * 6,   # csr_arrays()
                        out_specs=(P(), P(), P(), P(), P()), check_vma=False)
     return jax.jit(fn)
 
 
-def _matcher_args(store: SiteStore, use_csr: bool) -> Tuple[jax.Array, ...]:
-    """The device arrays a matcher built with ``use_csr`` expects."""
-    args: Tuple[jax.Array, ...] = (store.s, store.p, store.o)
-    if use_csr:
-        args += store.csr_arrays()
-    return args
+def _matcher_args(store: SiteStore) -> Tuple[jax.Array, ...]:
+    """The device arrays a matcher of ``make_spmd_matcher`` takes."""
+    return store.csr_arrays()
 
 
 def _unique_rows(rows: np.ndarray) -> Tuple[np.ndarray, bool]:
@@ -1268,13 +1148,10 @@ def spmd_match(store: SiteStore, mesh: Mesh, axis: str,
                pattern: QueryGraph, capacity: int = 4096
                ) -> Tuple[np.ndarray, List[int]]:
     """Run the SPMD matcher and return deduped host-side bindings."""
-    use_csr = store.csr_arrays() is not None
-    windows = ({e.prop: store.prop_window(e.prop) for e in pattern.edges}
-               if use_csr else None)
-    fn = make_spmd_matcher(mesh, axis, pattern, capacity, use_csr=use_csr,
-                           prop_windows=windows)
+    windows = {e.prop: store.prop_window(e.prop) for e in pattern.edges}
+    fn = make_spmd_matcher(mesh, axis, pattern, capacity, windows)
     bind, valid, _ovf, _dec, _rows = jax.device_get(
-        fn(*_matcher_args(store, use_csr)))
+        fn(*_matcher_args(store)))
     cols = pattern_var_order(pattern)
     rows, _ = _unique_rows(bind[np.asarray(valid)])
     return rows, cols
@@ -1575,16 +1452,15 @@ class SpmdEngine(EngineBase):
         key = (pattern.edges, capacity, self._store_gen)
         fn = self._matchers.get(key)
         if fn is None:
-            use_csr = self.store.csr_arrays() is not None
-            windows = ({e.prop: self.store.prop_window(e.prop)
-                        for e in pattern.edges} if use_csr else None)
+            windows = {e.prop: self.store.prop_window(e.prop)
+                       for e in pattern.edges}
             route = self._route(pattern)
             paths: Dict[str, int] = {}
             fn = make_spmd_matcher(self.mesh, self.axis, pattern, capacity,
+                                   windows,
                                    comm=self._comm_spec(pattern),
                                    seed_decimate=self._seed_decimation(
                                        pattern),
-                                   use_csr=use_csr, prop_windows=windows,
                                    route_ranks=(route.seed_ranks
                                                 if route is not None
                                                 else None),
@@ -1616,13 +1492,12 @@ class SpmdEngine(EngineBase):
             caps.append(cap)
             n_compiled = self._compiles
             fn = self._matcher(norm, cap)
-            use_csr = self.store.csr_arrays() is not None
             with (tr.span("match", capacity=cap,
                           compiled=self._compiles > n_compiled,
                           cycles=cycle_close_steps(norm),
                           edges=norm.num_edges)
                   if trace_on else NULL_SPAN) as match_sp:
-                out = fn(*_matcher_args(self.store, use_csr))
+                out = fn(*_matcher_args(self.store))
                 jax.block_until_ready(out)
             with (tr.span("fetch", bytes=sum(x.nbytes for x in out))
                   if trace_on else NULL_SPAN):
@@ -1926,10 +1801,9 @@ class SpmdEngine(EngineBase):
 
     def _stats_extra(self) -> Dict[str, float]:
         from ..kernels.ops import _interpret_default
-        kernels = sum(_use_pallas_probes(k) for k in MATCH_KERNELS)
+        kernels = sum(_use_pallas_probes(k) for k in TPU_KERNELS)
         # join paths summed over every matcher traced so far: which
-        # kernels the engine's programs contain, and which steps took
-        # the jnp composition
+        # kernels and which oracles the engine's programs contain
         traced = {f"traced_{name}": 0.0 for name in JOIN_PATHS}
         for paths in self._join_paths.values():
             for name, n in paths.items():
@@ -1944,6 +1818,4 @@ class SpmdEngine(EngineBase):
                 "routing": float(bool(self.routing and self.comm_plan
                                       and self.store.num_sites > 1)),
                 "replicated_props": float(len(self.replicated_props)),
-                "pallas_join_kernels": float(kernels),
-                "csr_prop_tables": float(
-                    self.store.csr_arrays() is not None)}
+                "pallas_join_kernels": float(kernels)}

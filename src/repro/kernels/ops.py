@@ -16,8 +16,7 @@ import numpy as np
 from . import ref
 from ..constants import INT32_SENTINEL, MAX_VERTEX_ID  # noqa: F401
 from .flash_attention import flash_attention
-from .semijoin import (BM, BN, dedup_blocks, fused_join_blocks,
-                       pair_semijoin_blocks, semijoin_blocks)
+from .semijoin import BM, BN, pair_semijoin_blocks, semijoin_blocks
 
 #: the shared pad/fill sentinel (see ``repro.constants``): sorts last,
 #: never equals a real vertex id (ids are bounded by ``MAX_VERTEX_ID``,
@@ -204,63 +203,6 @@ def pair_semijoin(q_s: jax.Array, q_o: jax.Array,
     mask_sorted = got.reshape(-1)[:nq] > 0
     inv = jnp.zeros_like(qorder).at[qorder].set(jnp.arange(nq))
     return mask_sorted[inv]
-
-
-# ----------------------------------------------------------------------
-# Hash dedup / fused dedup->expand->filter join
-# ----------------------------------------------------------------------
-
-def _hash_size(C: int) -> int:
-    """Power-of-two open-addressing table size >= 2C (load factor
-    <= 0.5, so probing terminates fast and an empty slot always
-    exists)."""
-    H = 8
-    while H < 2 * C:
-        H *= 2
-    return H
-
-
-def _column_major(bind: jax.Array) -> jax.Array:
-    return bind.astype(jnp.int32).T.reshape(-1)
-
-
-def dedup_rows(bind: jax.Array, valid: jax.Array,
-               interpret: Optional[bool] = None) -> jax.Array:
-    """First-occurrence keep mask over the valid rows of a padded
-    binding table: ``keep[i]`` is True iff ``valid[i]`` and no earlier
-    valid row equals row ``i``.  Exact (open-addressed int32 hash with
-    full-row compare on collision) and in place -- unlike the lexsort
-    oracle it never reorders rows, which no caller depends on anyway.
-    Every operand lives in SMEM (1 MiB on a v5e core), so on a chip the
-    table has to fit there."""
-    C, V = bind.shape
-    keep = dedup_blocks(_column_major(bind), valid.astype(jnp.int32),
-                        C, V, _hash_size(C),
-                        interpret=_interpret_default(interpret))
-    return keep > 0
-
-
-def fused_join(bind: jax.Array, valid: jax.Array, probe: jax.Array,
-               keys_sorted: jax.Array, payload: jax.Array, capacity: int,
-               interpret: Optional[bool] = None
-               ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Fused ``dedup_rows`` + join-expand against a sorted (keys ->
-    payload) edge table, one kernel pass (the SPMD gather step without
-    materializing the deduped table).  Same contract as
-    ``core.spmd._expand_fixed`` composed after a dedup: returns
-    (new_bind (capacity, V), new_col, new_valid, overflow) where
-    overflow counts result rows that did not fit (identical to the
-    composition's count, including the int32 cumsum wrap-risk guard);
-    output row *placement* differs (original gathered order, not
-    lexsorted), which no caller observes.  Every operand and output
-    lives in SMEM, as for ``dedup_rows``."""
-    C, V = bind.shape
-    nb, nc, nv, over = fused_join_blocks(
-        _column_major(bind), valid.astype(jnp.int32),
-        probe.astype(jnp.int32), keys_sorted.astype(jnp.int32),
-        payload.astype(jnp.int32), C, V, capacity, _hash_size(C),
-        interpret=_interpret_default(interpret))
-    return nb.reshape(V, capacity).T, nc, nv > 0, over[0]
 
 
 # ----------------------------------------------------------------------

@@ -1,4 +1,5 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
+"""Pure-jnp oracles for every Pallas kernel in this package, and for
+the SPMD match loop's gathered-row dedup and expansion step.
 
 These are the semantics of record: kernel tests sweep shapes/dtypes and
 ``assert_allclose`` against these functions.  They are also the fallback
@@ -106,9 +107,10 @@ def pair_semijoin_ref(q_s: jax.Array, q_o: jax.Array,
 
 def dedup_rows_ref(bind: jax.Array, valid: jax.Array) -> jax.Array:
     """keep[i] = valid[i] and no earlier valid row j < i has
-    bind[j] == bind[i] (all columns).  The semantics of record for the
-    hash-dedup kernel: exact, first occurrence by original index, keep
-    mask returned in original row positions.
+    bind[j] == bind[i] (all columns).  The oracle of the match loop's
+    gathered-row dedup (``core.spmd._dedup_padded``): exact, first
+    occurrence by original index, keep mask returned in original row
+    positions.
 
     Implemented as a stable column-wise lexsort (valid rows first,
     ties preserve original order, so the first of each duplicate run is

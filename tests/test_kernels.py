@@ -202,14 +202,14 @@ def test_attention_kernel_matches_inside_jit():
 
 
 # ----------------------------------------------------------------------
-# Hash-dedup kernel vs the lexsort oracle (semantics of record), and
-# the fused dedup->expand->filter join kernel vs the oracle
-# composition used by core.spmd off-TPU.  Adversarial inputs: padded
-# all-sentinel blocks, duplicate-heavy tables, capacity overflow, and
-# empty (all-sentinel) property tables.
+# The match loop's gathered step: the lexsort dedup (``_dedup_padded``)
+# vs its oracle ``ref.dedup_rows_ref`` and a brute-force first-occurrence
+# scan, and the dedup -> ``_expand_fixed`` composition vs a plain numpy
+# join.  Adversarial inputs: padded all-sentinel blocks, duplicate-heavy
+# tables, capacity overflow, and empty (all-sentinel) property tables.
 # ----------------------------------------------------------------------
 
-from repro.kernels import dedup_rows, fused_join  # noqa: E402
+from repro.core import spmd as S  # noqa: E402
 
 
 def _bind_case(C, V, style, seed):
@@ -241,22 +241,30 @@ def _first_occurrence_keep(bind, valid):
     return keep
 
 
+def _sorted_rows(rows):
+    rows = np.asarray(rows)
+    return rows[np.lexsort(rows.T[::-1])] if rows.size else rows
+
+
 @pytest.mark.parametrize("C,V", [(8, 1), (64, 3), (256, 2), (128, 5),
                                  (512, 4)])
 @pytest.mark.parametrize("style", ["random", "dup_heavy", "all_sentinel",
                                    "all_valid_distinct"])
-def test_dedup_rows_matches_oracle(C, V, style):
+def test_dedup_padded_matches_oracle(C, V, style):
+    """The lexsort dedup keeps one row of every distinct valid row, the
+    same rows as the oracle's and the brute-force scan's keep masks (it
+    returns them reordered, so kept rows are compared, not masks)."""
     bind, valid = _bind_case(C, V, style, seed=C * 31 + V)
-    got = np.asarray(dedup_rows(jnp.asarray(bind), jnp.asarray(valid)))
-    # the lexsort oracle keeps one row per distinct value set ...
+    out, keep = S._dedup_padded(jnp.asarray(bind), jnp.asarray(valid))
+    out, keep = np.asarray(out), np.asarray(keep)
     want_ref = np.asarray(ref.dedup_rows_ref(jnp.asarray(bind),
                                              jnp.asarray(valid)))
-    # ... and the kernel's contract pins *which* one: the earliest index
     want_brute = _first_occurrence_keep(bind, valid)
-    np.testing.assert_array_equal(got, want_brute)
-    assert got.sum() == want_ref.sum()
-    np.testing.assert_array_equal(
-        np.sort(bind[got], axis=0), np.sort(bind[want_ref], axis=0))
+    np.testing.assert_array_equal(want_ref, want_brute)
+    assert keep.sum() == want_brute.sum()
+    np.testing.assert_array_equal(_sorted_rows(out[keep]),
+                                  _sorted_rows(bind[want_brute]))
+    assert (out[~keep] == -1).all()
 
 
 def _edge_table(T, n_real, key_range, seed):
@@ -269,21 +277,27 @@ def _edge_table(T, n_real, key_range, seed):
     return keys, payload
 
 
-def _oracle_join(bind, valid, probe, keys, payload, capacity, monkeypatch):
-    """The off-TPU composition of record: lexsort dedup + _expand_fixed
-    (REPRO_SPMD_PALLAS pinned to 0 so CI kernel runs still diff against
-    the jnp oracle)."""
-    from repro.core import spmd as S
-    monkeypatch.setenv("REPRO_SPMD_PALLAS", "0")
-    db, dv = S._dedup_padded(jnp.asarray(bind), jnp.asarray(valid))
-    # rebuild per-row probes exactly like exp_via_gather: column lookup
-    # on the (possibly reordered) deduped table
-    dprobe = np.asarray(db)[:, _PROBE_COL]
-    return S._expand_fixed(db, dv, jnp.asarray(dprobe),
-                           jnp.asarray(keys), jnp.asarray(payload), capacity)
-
-
 _PROBE_COL = 0        # probe on the first binding column throughout
+
+
+def _gathered_step(bind, valid, keys, payload, capacity):
+    """The match loop's binding-gather step: dedup the gathered rows,
+    then expand them, probing their first column (the probe column is
+    read from the deduped table, as ``exp_via_gather`` reads it)."""
+    db, dv = S._dedup_padded(jnp.asarray(bind), jnp.asarray(valid))
+    return S._expand_fixed(db, dv, db[:, _PROBE_COL], jnp.asarray(keys),
+                           jnp.asarray(payload), capacity)
+
+
+def _numpy_join(bind, valid, keys, payload):
+    """(result rows as a multiset, their total) of the plain join: every
+    distinct valid row, once per edge whose key equals its probe."""
+    out = {}
+    for row in {tuple(r) for r in bind[valid].tolist()}:
+        for k in np.flatnonzero(keys == row[_PROBE_COL]):
+            r = row + (int(payload[k]),)
+            out[r] = out.get(r, 0) + 1
+    return out, sum(out.values())
 
 
 def _row_multiset(nb, nc, nv):
@@ -303,48 +317,36 @@ def _row_multiset(nb, nc, nv):
     (256, 4, 128, 1024),
 ])
 @pytest.mark.parametrize("style", ["random", "dup_heavy", "all_sentinel"])
-def test_fused_join_matches_oracle_composition(C, V, T, capacity, style,
-                                               monkeypatch):
+def test_gathered_step_matches_numpy_join(C, V, T, capacity, style):
     bind, valid = _bind_case(C, V, style, seed=C + T)
     keys, payload = _edge_table(T, max(T // 2, 1), 40, seed=C * T)
-    probe = bind[:, _PROBE_COL]
-    got = fused_join(jnp.asarray(bind), jnp.asarray(valid),
-                     jnp.asarray(probe), jnp.asarray(keys),
-                     jnp.asarray(payload), capacity)
-    want = _oracle_join(bind, valid, probe, keys, payload, capacity,
-                        monkeypatch)
-    assert int(got[3]) == int(want[3]), "overflow counts diverged"
+    got = _gathered_step(bind, valid, keys, payload, capacity)
+    want, total = _numpy_join(bind, valid, keys, payload)
+    assert total <= capacity
     assert int(got[3]) == 0
-    assert _row_multiset(*got[:3]) == _row_multiset(*want[:3])
+    assert _row_multiset(*got[:3]) == want
 
 
-def test_fused_join_empty_property_table(monkeypatch):
+def test_gathered_step_empty_property_table():
     """An empty property on this shard: every key is the sentinel, so
     the join yields zero rows and zero overflow."""
     bind, valid = _bind_case(64, 2, "random", seed=9)
     keys = np.full(16, INT32_MAX, np.int32)
     payload = np.full(16, -1, np.int32)
-    got = fused_join(jnp.asarray(bind), jnp.asarray(valid),
-                     jnp.asarray(bind[:, 0]), jnp.asarray(keys),
-                     jnp.asarray(payload), 128)
+    got = _gathered_step(bind, valid, keys, payload, 128)
     assert int(got[3]) == 0 and not bool(np.asarray(got[2]).any())
-    want = _oracle_join(bind, valid, bind[:, 0], keys, payload, 128,
-                        monkeypatch)
-    assert int(want[3]) == 0 and not bool(np.asarray(want[2]).any())
+    assert _numpy_join(bind, valid, keys, payload) == ({}, 0)
 
 
 @pytest.mark.parametrize("capacity", [1, 4, 16])
-def test_fused_join_overflow_counts_match_composition(capacity,
-                                                      monkeypatch):
+def test_gathered_step_overflow_count(capacity):
     """Under capacity overflow the retry ladder only consumes the
-    overflow *count*; fused kernel and oracle composition must agree on
-    it exactly (truncated content is discarded either way)."""
+    overflow *count*: it is the plain join's rows past capacity, and
+    every slot below capacity holds a row of the join."""
     bind, valid = _bind_case(128, 2, "dup_heavy", seed=3)
     keys, payload = _edge_table(64, 64, 3, seed=4)   # dense key collisions
-    probe = bind[:, _PROBE_COL]
-    got = fused_join(jnp.asarray(bind), jnp.asarray(valid),
-                     jnp.asarray(probe), jnp.asarray(keys),
-                     jnp.asarray(payload), capacity)
-    want = _oracle_join(bind, valid, probe, keys, payload, capacity,
-                        monkeypatch)
-    assert int(got[3]) == int(want[3]) > 0
+    got = _gathered_step(bind, valid, keys, payload, capacity)
+    want, total = _numpy_join(bind, valid, keys, payload)
+    assert int(got[3]) == max(total - capacity, 0) > 0
+    assert int(np.asarray(got[2]).sum()) == capacity
+    assert all(r in want for r in _row_multiset(*got[:3]))

@@ -280,8 +280,7 @@ def test_matcher_ops_carry_step_and_final_gather_scopes(spmd_setup):
     eng = sess.engine
     q = _shape_queries(g, per_shape=1)[1].normalize()
     fn = eng._matcher(q, 64)
-    use_csr = eng.store.csr_arrays() is not None
-    text = fn.lower(*S._matcher_args(eng.store, use_csr)).as_text(
+    text = fn.lower(*S._matcher_args(eng.store)).as_text(
         debug_info=True)
     for j in range(q.num_edges):
         assert f"step{j}/" in text

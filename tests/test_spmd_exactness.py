@@ -161,11 +161,11 @@ def test_pallas_probe_path_is_exact_end_to_end(rgraph, monkeypatch):
 @pytest.mark.parametrize("pallas", ["0", "1"], ids=["oracle", "kernel"])
 def test_join_kernel_toggle_answer_sets_identical(rgraph, rqueries,
                                                   monkeypatch, pallas):
-    """The fused dedup->expand->filter join kernel and the hash-dedup
-    kernel (REPRO_SPMD_PALLAS=1, interpret mode on CPU) produce answer
-    sets identical to the lexsort/jnp oracle path (=0) -- end to end
-    through the engine, star/chain/cycle shapes, forcing at least one
-    overflow retry tier with a small starting capacity."""
+    """The blocked ``join_count`` and ``pair_semijoin`` probe kernels
+    (REPRO_SPMD_PALLAS=1, interpret mode on CPU) produce answer sets
+    identical to their jnp oracles (=0) -- end to end through the
+    engine, star/chain/cycle shapes, forcing at least one overflow
+    retry tier with a small starting capacity."""
     monkeypatch.setenv("REPRO_SPMD_PALLAS", pallas)
     plan = build_plan(rgraph, Workload(list(rqueries)),
                       PartitionConfig(kind="vertical", num_sites=4))
@@ -261,8 +261,13 @@ def test_sitestore_pads_empty_sites_to_pad_multiple(rgraph):
     from repro.core.spmd import SiteStore
     store = SiteStore.build(rgraph, [np.zeros(0, np.int64)] * 4)
     assert store.e_max == 512            # 0 edges still pad to a full block
-    assert store.s.shape == (4, 512)
-    assert int(np.asarray(store.p).max()) == -1   # all padding
+    for keys in (store.csr_sub_s, store.csr_obj_o):   # all padding
+        assert keys.shape == (4, store.e_max + store.csr_pad)
+        assert (np.asarray(keys) == np.iinfo(np.int32).max).all()
+    for pay in (store.csr_sub_o, store.csr_obj_s):
+        assert (np.asarray(pay) == -1).all()
+    assert not np.asarray(store.owned).any()
+    assert not np.asarray(store.csr_offs).any()
 
 
 def test_all_empty_site_plan_executes_cleanly(rgraph):

@@ -189,15 +189,18 @@ def test_hlocost_plain_matmul():
 # ----------------------------------------------------------------------
 
 def test_spmd_local_match_equals_host_matcher(watdiv_small):
+    """``_match_shard`` on one shard's packed tables, no collectives."""
     from repro.core.matching import match_pattern
     from repro.core.query import QueryGraph
-    from repro.core.spmd import SiteStore, local_match
+    from repro.core.spmd import SiteStore, _match_shard
     g = watdiv_small
     store = SiteStore.build(g, [np.arange(g.num_edges)])
     pat = QueryGraph.make([(-1, -2, 1), (-2, -3, 8)])
     want = match_pattern(g, pat)
-    bind, valid, cols = local_match(store.s[0], store.p[0], store.o[0],
-                                    pat, 16384)
+    windows = {e.prop: store.prop_window(e.prop) for e in pat.edges}
+    bind, valid, cols, ovf, _dec, _rows = _match_shard(
+        tuple(a[0] for a in store.csr_arrays()), windows, pat, 16384)
+    assert int(ovf) == 0
     got = np.asarray(bind)[np.asarray(valid)]
     wrows = np.stack([want.columns[c] for c in cols], axis=1) \
         if want.num_rows else np.zeros((0, len(cols)), np.int32)

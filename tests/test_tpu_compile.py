@@ -61,8 +61,7 @@ def _abstract(args, sharding):
                                  sharding=sharding) for a in args]
 
 
-@pytest.mark.parametrize("name", ["semijoin", "join_count", "pair_semijoin",
-                                  "dedup_rows", "fused_join"])
+@pytest.mark.parametrize("name", ["semijoin", "join_count", "pair_semijoin"])
 def test_join_kernel_compiles_for_v5e(v5e, compiled_kernels, name):
     """Each join kernel at the shapes ``chip_smoke.py`` runs, its
     custom call named after the kernel (the name a profiler trace
@@ -79,17 +78,15 @@ def _store_and_args(num_sites, mesh):
     sites = [np.arange(j, g.num_edges, num_sites) for j in range(num_sites)]
     store = S.SiteStore.build(g, sites)
     sharded = NamedSharding(mesh, P("sites", None))
-    return store, _abstract(S._matcher_args(store, True), sharded)
+    return store, _abstract(S._matcher_args(store), sharded)
 
 
 def _compile_matcher(mesh, store, args, pattern, capacity=1024):
     paths = {}
     fn = S.make_spmd_matcher(
         mesh, "sites", pattern, capacity,
-        comm=S.plan_step_comm(store, pattern), use_csr=True,
-        prop_windows={e.prop: store.prop_window(e.prop)
-                      for e in pattern.edges},
-        join_paths=paths)
+        {e.prop: store.prop_window(e.prop) for e in pattern.edges},
+        comm=S.plan_step_comm(store, pattern), join_paths=paths)
     return fn.lower(*args).compile().as_text(), paths
 
 
@@ -113,8 +110,8 @@ def test_spmd_matcher_compiles_on_v5e_2x2_mesh(v5e, compiled_kernels,
     """A chain over properties spread across all four devices, with the
     kernels a TPU turns on by default: its join step ships between
     devices, so the program holds the blocked kernels and the
-    all-gather collectives, and the gathered rows take the jnp dedup +
-    expand composition."""
+    all-gather collectives, and the gathered rows take the lexsort
+    dedup before their expansion."""
     monkeypatch.delenv("REPRO_SPMD_PALLAS")
     mesh = Mesh(np.asarray(v5e.devices), ("sites",))
     store, args = _store_and_args(4, mesh)
@@ -125,7 +122,5 @@ def test_spmd_matcher_compiles_on_v5e_2x2_mesh(v5e, compiled_kernels,
                                    capacity=65536)
     assert "tpu_custom_call" in text
     assert "all-gather" in text
-    assert paths["jnp_join"] == 1 and paths["lexsort_dedup"] == 1
+    assert paths["lexsort_dedup"] == 1
     assert paths["join_count_kernel"] == 2
-    assert "fused_join_kernel" not in paths
-    assert "hash_dedup_kernel" not in paths
